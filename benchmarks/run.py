@@ -67,6 +67,8 @@ def main() -> None:
                     help="also write the emitted rows to this CSV file "
                          "(CI uploads it as an artifact)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import paper_tables, kernels_bench, serving_bench
     sections = {
         "table1": paper_tables.table1,

@@ -358,6 +358,8 @@ def main() -> None:
                          "list (e.g. 1,2,4,8) runs the closed-loop sweep "
                          "and prints one CSV row per K")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     blocks = [int(k) for k in str(args.decode_block).split(",")]
     if len(blocks) > 1:
         print("decode_block,decode_steps,decode_micro_steps,"

@@ -50,14 +50,13 @@ import numpy as np
 
 from .epitome import (
     EpitomeSpec,
-    epitome_matmul_ref,
     folded_matmul,
     init_epitome,
     reconstruct,
     wrapped_matmul,
 )
 from .placement import LayerPlacement
-from .quant import QuantConfig, fake_quant
+from .quant import QuantConfig, dequantize_packed, fake_quant
 
 Array = jax.Array
 
@@ -152,7 +151,27 @@ def _quant_kernel_inference_only(x: Array, E: Array, cfg: EpLayerConfig,
                                  packed) -> Array:
     arrays = ((packed.q, packed.scales, packed.zeros)
               if packed is not None else _pack_arrays(E, cfg=cfg))
-    return _quant_kernel_apply(cfg, x, arrays)
+    return _whole_on_each_device(functools.partial(_quant_kernel_apply, cfg),
+                                 x, arrays)
+
+
+def _whole_on_each_device(kernel_fn, *args):
+    """Run a fused-kernel call under the installed serving mesh.
+
+    GSPMD cannot partition a Mosaic kernel (the chip's compiler refuses
+    one with sharded operands), so on a multi-device mesh the call runs
+    inside a shard_map with every operand replicated: each device gathers
+    the layer's operands and computes the whole output.  Weights stay
+    sharded at rest; only the compute is replicated.  Without a mesh, or
+    on one device, the call is made as is."""
+    from jax.sharding import PartitionSpec as P
+    from ..models.common import get_mesh
+    mesh = get_mesh()
+    if mesh is None or mesh.size == 1:
+        return kernel_fn(*args)
+    return jax.shard_map(kernel_fn, mesh=mesh,
+                         in_specs=jax.tree.map(lambda _: P(), args),
+                         out_specs=P(), check_vma=False)(*args)
 
 
 def prepack_linear(params: dict, cfg: EpLayerConfig) -> dict:
@@ -283,14 +302,20 @@ def effective_weight(params: dict, cfg: EpLayerConfig) -> Array:
 
     Used by 'reconstruct' mode, by the quantization parity tests, and as
     the reference the kernel modes are compared against on aligned specs;
-    'wrapped'/'kernel' modes never materialize the full W at runtime."""
+    'wrapped'/'kernel' modes never materialize the full W at runtime.
+    Prepacked params (``prepack_linear``) dequantize their stored int8
+    codes, so a reference forward over a served param tree multiplies by
+    exactly the weights the fused kernel reads."""
     if cfg.is_epitome:
         E = params["E"]
-        if cfg.quant is not None:
+        if cfg.quant is not None and "Eq" in params:
+            p = _packed_of(params, cfg)
+            E = dequantize_packed(p.q, p.scales, p.zeros,
+                                  (p.bk, p.bn)).astype(E.dtype)
+        elif cfg.quant is not None:
             if cfg.mode == "kernel":
                 # mirror the fused path's packed (int8, per-block s/z) quant
                 from repro.kernels.ops import pack_epitome
-                from .quant import dequantize_packed
                 p = pack_epitome(E, cfg.spec, cfg.quant, blocks=cfg.blocks)
                 E = dequantize_packed(p.q, p.scales, p.zeros,
                                       (p.bk, p.bn)).astype(E.dtype)
@@ -319,11 +344,12 @@ def _dispatch_epitome_matmul(params: dict, x: Array, cfg: EpLayerConfig) -> Arra
             packed = _packed_of(params, cfg) if "Eq" in params else None
             return _quant_kernel_inference_only(x, E, cfg, packed)
         from repro.kernels.ops import epitome_matmul
-        return epitome_matmul(x, E, cfg.spec)
+        return _whole_on_each_device(
+            functools.partial(epitome_matmul, spec=cfg.spec), x, E)
+    if cfg.mode == "reconstruct":
+        return x @ effective_weight(params, cfg).astype(x.dtype)
     if cfg.quant is not None:
         E = fake_quant(E, cfg.spec, cfg.quant)
-    if cfg.mode == "reconstruct":
-        return epitome_matmul_ref(x, E, cfg.spec)
     if cfg.mode == "wrapped":
         return wrapped_matmul(x, E, cfg.spec)
     if cfg.mode == "folded":
@@ -348,24 +374,10 @@ def exact_dot(x: Array, W: Array) -> Array:
     which is already deterministic across geometries."""
     if jnp.issubdtype(x.dtype, jnp.floating) and \
             jnp.finfo(x.dtype).bits < jnp.finfo(jnp.float32).bits:
-        xb, Wb = _pin(x), _pin(W.astype(x.dtype))
+        xb = jax.lax.optimization_barrier(x)
+        Wb = jax.lax.optimization_barrier(W.astype(x.dtype))
         return (xb.astype(jnp.float32) @ Wb.astype(jnp.float32)).astype(x.dtype)
     return x @ W.astype(x.dtype)
-
-
-@jax.custom_jvp
-def _pin(x: Array) -> Array:
-    """optimization_barrier with a pass-through tangent: the barrier has no
-    differentiation rule on this jax version, and training doesn't need the
-    rounding pinned anyway — only the serving forward's cross-geometry bits
-    do."""
-    return jax.lax.optimization_barrier(x)
-
-
-@_pin.defjvp
-def _pin_jvp(primals, tangents):
-    (x,), (t,) = primals, tangents
-    return _pin(x), t
 
 
 def apply_linear(params: dict, x: Array, cfg: EpLayerConfig) -> Array:
@@ -438,10 +450,7 @@ def apply_conv(params: dict, x: Array, kh: int, kw: int, cin: int, cout: int,
         patches = im2col(x, kh, kw, stride=stride, padding=padding)
         return _dispatch_epitome_matmul(params, patches, cfg)
     if cfg.is_epitome:
-        E = params["E"]
-        if cfg.quant is not None:
-            E = fake_quant(E, cfg.spec, cfg.quant)
-        W = reconstruct(E, cfg.spec).reshape(kh, kw, cin, cout)
+        W = effective_weight(params, cfg).reshape(kh, kw, cin, cout)
     else:
         W = params["W"]
         if cfg.quant is not None:

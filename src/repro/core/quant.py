@@ -59,16 +59,18 @@ def _masked_min_max(x: Array, mask: Array) -> Tuple[Array, Array]:
 
 def overlap_weighted_range(E: Array, spec: EpitomeSpec, w1: float, w2: float) -> Tuple[Array, Array]:
     """Eq. 4-5: weighted min/max over the overlap region vs. the rest."""
-    m = jnp.asarray(overlap_mask(spec))
-    any_ovl = m.any()
+    # the mask is a host constant: decide its degenerate cases here, since
+    # XLA constant-folds an in-graph any/all over an (m, n) mask for
+    # seconds per layer shape at real widths
+    mask = overlap_mask(spec)
+    m = jnp.asarray(mask)
     mn_o, mx_o = _masked_min_max(E, m)
     mn_r, mx_r = _masked_min_max(E, ~m)
     # degenerate cases: everything (or nothing) is overlap -> plain min/max
-    mn_o = jnp.where(any_ovl, mn_o, mn_r)
-    mx_o = jnp.where(any_ovl, mx_o, mx_r)
-    all_ovl = m.all()
-    mn_r = jnp.where(all_ovl, mn_o, mn_r)
-    mx_r = jnp.where(all_ovl, mx_o, mx_r)
+    if not mask.any():
+        mn_o, mx_o = mn_r, mx_r
+    if mask.all():
+        mn_r, mx_r = mn_o, mx_o
     alpha = w1 * mn_o + w2 * mn_r
     beta = w1 * mx_o + w2 * mx_r
     return alpha, beta

@@ -22,7 +22,8 @@ Results persist in a JSON cache under ``benchmarks/tuned/<backend>.json``
 keyed by (spec signature, bits, T bucket); the file records the backend
 and jax version, and a mismatching signature invalidates the whole file
 (graceful fallback: re-tune, never crash).  If timing is unavailable the
-tuner degrades to the heuristic blocks with ``source='heuristic'``.
+tuner degrades to the heuristic blocks with ``source='heuristic'``; a
+heuristic-blocks kernel that fails to build or run raises.
 
     from repro.kernels.autotune import tune, tune_plan
     res = tune(spec, bits=3, T=196)             # TuneResult
@@ -119,7 +120,7 @@ def candidate_blocks(spec: EpitomeSpec, T: int, *, bits: int = 0,
 
     bts = [b for b in ops._BT_BLOCKS if b <= Tb]
     bk_cap = min(tile, spec.m) if quant else spec.m
-    bks = [b for b in ops._BK_BLOCKS if b <= bk_cap]
+    bks = [b for b in ops._BK_LANE_BLOCKS if b <= bk_cap]
     bns = [b for b in (512, 256, 128) if b < spec.bn
            and ops.col_blocks_splittable(spec, b)]
     bns = [spec.bn] + bns
@@ -214,7 +215,8 @@ def tune(spec: EpitomeSpec, bits: int, T: int, *,
     """Measure the candidate grid for (spec, bits, T bucket) and return the
     winner; a prior winner in the JSON cache short-circuits the sweep
     (``source='cache'``), and a failing timer degrades to the heuristic
-    blocks (``source='heuristic'``).  ``bits=0`` tunes the unquantized fp
+    blocks (``source='heuristic'``).  A candidate that fails to build or
+    run is skipped; the heuristic baseline failing raises.  ``bits=0`` tunes the unquantized fp
     kernel; otherwise the fused int8 kernel at that weight width."""
     backend = jax.default_backend()
     cache_dir = default_cache_dir() if cache_dir is None else cache_dir
@@ -275,10 +277,9 @@ def tune(spec: EpitomeSpec, bits: int, T: int, *,
                          backend=backend, key=key)
         return res
 
-    try:
-        baseline = np.asarray(runner(heur, False)())
-    except Exception:
-        return fallback("baseline failed")
+    # the heuristic blocks are what serving runs untuned: if they do not
+    # build or run, that is a kernel fault to surface, not a tuning result
+    baseline = np.asarray(runner(heur, False)())
 
     variants: List[Tuple[Blocks, bool]] = [(c, False) for c in cands]
     if include_fused_fold and quant:

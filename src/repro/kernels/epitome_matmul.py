@@ -78,7 +78,7 @@ def epitome_matmul_blocks(x_folded: Array, E: Array, col_blocks,
             scratch_shapes=[pltpu.VMEM((bt, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((T, gn * bn), x_folded.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(col_blocks, x_folded, E)

@@ -3,8 +3,9 @@
 `epitome_matmul` is what core/layers.py mode="kernel" calls: it folds the
 activations into epitome-row space (the IFRT analogue, a cheap segment-sum),
 runs the MXU kernel with the static OFAT offset table, and trims the result
-to the virtual width.  On CPU (tests / this container) everything runs with
-interpret=True; on TPU the same code JITs to Mosaic.
+to the virtual width.  On the CPU backend (the unit tests) the kernels run
+in Pallas interpret mode; on the chip the same code compiles to Mosaic.
+The choice is made per call (``interpret_mode``), never at import.
 """
 from __future__ import annotations
 
@@ -23,7 +24,14 @@ from .quant_epitome_matmul import (quant_epitome_matmul_blocks,
 from .quant_matmul import quant_matmul as _quant_matmul
 from .wkv6 import wkv6_chunked
 
-_INTERPRET = jax.default_backend() == "cpu"
+
+def interpret_mode(interpret: Optional[bool] = None) -> bool:
+    """Whether a kernel call runs in Pallas interpret mode: an explicit
+    ``interpret`` wins; otherwise only where JAX's default backend is the
+    CPU — so a chip run always compiles the kernels to Mosaic."""
+    if interpret is not None:
+        return interpret
+    return jax.default_backend() == "cpu"
 
 
 def kernel_col_blocks(spec: EpitomeSpec,
@@ -76,7 +84,7 @@ def epitome_matmul(x: jax.Array, E: jax.Array, spec: EpitomeSpec,
     per row regardless of how many kernel windows produced it.  bt/bk/bn
     override the heuristic block shapes (an autotuned triple); a bk that
     tiles m raggedly zero-pads the contraction dim (dot-neutral)."""
-    interpret = _INTERPRET if interpret is None else interpret
+    interpret = interpret_mode(interpret)
     *lead, M = x.shape
     x2 = x.reshape(-1, M)
     T = x2.shape[0]
@@ -120,23 +128,31 @@ def _pad_rows(x2: jax.Array, bt: Optional[int] = None) -> tuple:
     return x2, bt
 
 
-_BK_BLOCKS = (512, 256, 128, 64, 32, 16, 8)
+# Contraction-block menu.  bk is the lane (last) dim of the activation
+# block, so on the chip it must be a multiple of 128 or the whole (padded)
+# contraction dim; the sub-128 entries serve only quant tiles narrower
+# than 128, which no chip configuration uses (interpret-mode tests).
+_BK_LANE_BLOCKS = (512, 256, 128)
+_BK_NARROW_BLOCKS = (64, 32, 16, 8)
+
+
+def _pick_k_block(m: int, cap: int) -> int:
+    """Contraction block for an m-row epitome, at most ``cap`` wide.  An m
+    that fits under the cap is one whole block (the full dim: legal at any
+    m, no padding).  Otherwise the lane-legal block that pads m least wins,
+    the larger on a tie; the caller zero-pads the contraction dim up to a
+    block multiple (``_pad_contraction``: zero activation columns make the
+    padded weight rows dot-neutral)."""
+    if m <= cap:
+        return m
+    menu = [b for b in _BK_LANE_BLOCKS if b <= cap] or \
+        [b for b in _BK_NARROW_BLOCKS if b <= cap][:1]
+    return min(menu, key=lambda b: (-(-m // b) * b, -b))
 
 
 def _pick_bk(m: int) -> int:
-    """Contraction block for an m-row epitome.  Prefers the largest block
-    dividing m exactly; a prime/odd m no longer falls through to bk = m
-    (one giant VMEM-blowing block) — it takes the largest standard block
-    not exceeding m and the caller zero-pads the contraction dim up to a
-    block multiple (``_pad_contraction``; zero activation columns make the
-    padded weight rows dot-neutral)."""
-    for bk in _BK_BLOCKS:
-        if m % bk == 0:
-            return bk
-    for bk in _BK_BLOCKS:
-        if bk <= m:
-            return bk
-    return m                  # m < 8: a single (tiny) block
+    """Contraction block of the fp epitome kernel (at most 512 rows)."""
+    return _pick_k_block(m, _BK_LANE_BLOCKS[0])
 
 
 def _pad_contraction(folded: jax.Array, w_rows: jax.Array, bk: int) -> tuple:
@@ -155,7 +171,7 @@ def _pad_contraction(folded: jax.Array, w_rows: jax.Array, bk: int) -> tuple:
 def wkv6(r, k, v, logw, u, *, chunk: int = 64,
          interpret: Optional[bool] = None):
     """r/k/v/logw: (B, S, H, K); u: (H, K) -> (B, S, H, K)."""
-    interpret = _INTERPRET if interpret is None else interpret
+    interpret = interpret_mode(interpret)
     B, S, H, K = r.shape
     to_bh = lambda t: t.transpose(0, 2, 1, 3).reshape(B * H, S, K)
     pad = (-S) % chunk
@@ -171,7 +187,7 @@ def wkv6(r, k, v, logw, u, *, chunk: int = 64,
 
 
 def quant_matmul(x, q, scales, zeros, *, interpret: Optional[bool] = None):
-    interpret = _INTERPRET if interpret is None else interpret
+    interpret = interpret_mode(interpret)
     *lead, M = x.shape
     x2 = x.reshape(-1, M)
     T = x2.shape[0]
@@ -196,17 +212,10 @@ class PackedEpitome(NamedTuple):
 def _pick_bk_quant(m: int, tile: int) -> int:
     """Row-block for the quant kernel: never wider than the quantizer's
     crossbar tile, so each kernel block nests inside one scale tile and the
-    packed codes stay bit-identical to fake_quant's.  Same prime/odd-m
-    fallback as ``_pick_bk``: the largest standard block not exceeding
-    min(tile, m) instead of one giant bk = m block (the ragged tail is
-    zero-padded at kernel-call time, never inside the quantizer)."""
-    for bk in _BK_BLOCKS[1:]:
-        if bk <= tile and m % bk == 0:
-            return bk
-    for bk in _BK_BLOCKS[1:]:
-        if bk <= min(tile, m):
-            return bk
-    return m
+    packed codes stay bit-identical to fake_quant's.  Same menu as
+    ``_pick_bk`` (the ragged tail is zero-padded at kernel-call time, never
+    inside the quantizer)."""
+    return _pick_k_block(m, tile)
 
 
 def pack_blocks(spec: EpitomeSpec, qcfg: QuantConfig,
@@ -246,8 +255,11 @@ def quant_epitome_matmul(x: jax.Array, E: Optional[jax.Array],
     decode batch reuses one tuned row block instead of re-picking per T;
     ``fused_fold=True`` runs the fold inside the kernel (the folded
     activation never round-trips HBM — decode-path pipelining).  Both come
-    from kernels/autotune.py via plan provenance."""
-    interpret = _INTERPRET if interpret is None else interpret
+    from kernels/autotune.py via plan provenance.  The fused-fold kernel
+    puts bt on the lane dim of its (Mp, bt) activation block, so its
+    default bt is the whole (8-padded) T up to 128 rows and 128 beyond —
+    a multiple of 128 or the full dim, as Mosaic requires."""
+    interpret = interpret_mode(interpret)
     if packed is None:
         assert E is not None and qcfg is not None
         packed = pack_epitome(E, spec, qcfg)
@@ -261,6 +273,8 @@ def quant_epitome_matmul(x: jax.Array, E: Optional[jax.Array],
         q = jnp.pad(q, ((0, pad_m), (0, 0)))
     cb = kernel_col_blocks(spec, bn)
     if fused_fold:
+        if bt is None:
+            bt = min(-(-T // 8) * 8, 128)
         x2p, bt = _pad_rows(x2.astype(jnp.float32), bt)
         gm, bm = spec.gm, spec.bm
         xt = jnp.pad(x2p, ((0, 0), (0, gm * bm - M))).T   # (Mp, Tp)

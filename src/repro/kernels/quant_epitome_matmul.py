@@ -14,14 +14,19 @@ for every output-column block j, where
     way into VMEM, so HBM traffic is 4x smaller than bf16 x2 and the whole
     (already CR-x-compressed) epitome is read from HBM exactly once;
   * ``(s, z)`` are one (scale, zero) pair per kernel block (bk x bn) — the
-    crossbar-tile contract shared with quant_matmul: each grid step consumes
-    exactly one scalar pair, dequantized in registers right before the dot;
+    crossbar-tile contract shared with quant_matmul: each grid step reads
+    exactly one scalar pair, dequantized in registers right before the dot.
+    Both tables live whole in SMEM and are indexed ``[k, cb[j]]`` in the
+    body: a (1, 1) VMEM block per grid step breaks Mosaic's rule that block
+    dims be multiples of (8, 128) or the full array dims;
   * ``cb`` is the scalar-prefetched OFAT column-block table from
     kernel_col_blocks.  Duplicated entries ARE the paper's output channel
     wrapping: the same int8 block is re-read from VMEM for free.
 
 Grid: (T/bt, gn, m/bk), k innermost for accumulation.  VMEM per step:
-x (bt, bk) + Q (bk, bn) int8 + acc (bt, bn) fp32 + two scalars.
+x (bt, bk) + Q (bk, bn) int8 + acc (bt, bn) fp32.  The caller (ops.py)
+picks bk as a multiple of 128 or the whole (padded) m, so the activation
+block's lane dim is legal on the chip.
 
 Two pipelining levers live here (the kernel half of kernels/autotune.py):
 
@@ -48,13 +53,19 @@ from jax.experimental.pallas import tpu as pltpu
 Array = jax.Array
 
 
+def _dequant(q_ref, s_ref, z_ref, cb_ref):
+    """This grid step's int8 code tile, dequantized in registers with its
+    one (scale, zero) pair read from the SMEM tables."""
+    k, cb = pl.program_id(2), cb_ref[pl.program_id(1)]
+    return (q_ref[...].astype(jnp.float32) + z_ref[k, cb]) * s_ref[k, cb]
+
+
 def _kernel(cb_ref, x_ref, q_ref, s_ref, z_ref, o_ref, acc_ref, *, nk: int):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # dequantize this epitome block in registers: one (s, z) per block
-    w = (q_ref[...].astype(jnp.float32) + z_ref[0, 0]) * s_ref[0, 0]
+    w = _dequant(q_ref, s_ref, z_ref, cb_ref)
     acc_ref[...] += jnp.dot(x_ref[...].astype(jnp.float32), w,
                             preferred_element_type=jnp.float32)
 
@@ -94,14 +105,14 @@ def quant_epitome_matmul_blocks(x_folded: Array, q: Array, scales: Array,
             in_specs=[
                 pl.BlockSpec((bt, bk), lambda i, j, k, cb: (i, k)),
                 pl.BlockSpec((bk, bn), lambda i, j, k, cb: (k, cb[j])),
-                pl.BlockSpec((1, 1), lambda i, j, k, cb: (k, cb[j])),
-                pl.BlockSpec((1, 1), lambda i, j, k, cb: (k, cb[j])),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
             ],
             out_specs=pl.BlockSpec((bt, bn), lambda i, j, k, cb: (i, j)),
             scratch_shapes=[pltpu.VMEM((bt, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((T, gn * bn), x_folded.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(col_blocks, x_folded, q, scales, zeros)
@@ -127,7 +138,7 @@ def _fused_fold_kernel(cb_ref, ro_ref, xt_ref, q_ref, s_ref, z_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     k = pl.program_id(2)
-    w = (q_ref[...].astype(jnp.float32) + z_ref[0, 0]) * s_ref[0, 0]
+    w = _dequant(q_ref, s_ref, z_ref, cb_ref)
     xk = fold_ref[pl.dslice(k * (fold_ref.shape[0] // nk),
                             fold_ref.shape[0] // nk), :]
     # contract the fold scratch's row dim (epitome rows) against w's rows
@@ -171,8 +182,8 @@ def quant_epitome_matmul_fused_fold(xt: Array, q: Array, scales: Array,
             in_specs=[
                 pl.BlockSpec((Mp, bt), lambda i, j, k, cb, ro: (0, i)),
                 pl.BlockSpec((bk, bn), lambda i, j, k, cb, ro: (k, cb[j])),
-                pl.BlockSpec((1, 1), lambda i, j, k, cb, ro: (k, cb[j])),
-                pl.BlockSpec((1, 1), lambda i, j, k, cb, ro: (k, cb[j])),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
             ],
             out_specs=pl.BlockSpec((bt, bn), lambda i, j, k, cb, ro: (i, j)),
             scratch_shapes=[pltpu.VMEM((m, bt), jnp.float32),
@@ -181,7 +192,7 @@ def quant_epitome_matmul_fused_fold(xt: Array, q: Array, scales: Array,
         out_shape=jax.ShapeDtypeStruct((T, gn * bn), jnp.float32),
         # the fold scratch is shared across j and k for a fixed i, so only
         # the row-block dim may be reordered freely
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(col_blocks, row_offsets, xt, q, scales, zeros)

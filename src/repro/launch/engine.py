@@ -401,10 +401,14 @@ class EngineConfig:
         init_key, prompt_key, sample_key = jax.random.split(
             jax.random.PRNGKey(self.seed), 3)
         params = lm.init_params(init_key, cfg)
+        if shard_mesh is not None:
+            # lay the params out before packing, so the packed tree reuses
+            # the sharded E leaves: packing first would hold the whole
+            # model, its codes and every shard on the first device at once
+            # (more than one chip holds at real widths)
+            params = lm.shard_params(params, cfg, shard_mesh)
         packed = (lm.prepack_params(params, cfg, mesh=shard_mesh)
                   if self.prepack and lm.needs_prepack(cfg) else None)
-        if shard_mesh is not None:
-            params = lm.shard_params(params, cfg, shard_mesh)
         engine = EpimEngine(cfg, packed if packed is not None else params,
                             capacity=self.capacity, max_len=self.max_len,
                             page_size=self.page_size, kv_pages=self.kv_pages,

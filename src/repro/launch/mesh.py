@@ -8,17 +8,7 @@ import warnings
 from typing import Tuple
 
 import jax
-
-
-def compat_make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """jax.make_mesh across jax versions: `axis_types` (and
-    jax.sharding.AxisType) only exist from jax 0.5; older jaxlibs default to
-    Auto semantics anyway, so omit the kwarg when it's unavailable."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -27,26 +17,22 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     data parallelism whose gradient all-reduce crosses the DCN."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
-    """Small mesh over whatever devices exist (tests / CPU examples).
+    """(data, model) mesh over the first data*model local devices, with
+    Auto axes (sharding follows annotations and GSPMD propagation).
 
-    The requested (data, model) shape is clamped to the available device
-    count — with a warning when it degrades, so smoke tok/s numbers are
-    attributable to the mesh that actually ran rather than the one that
-    was asked for."""
+    A shape that needs more devices than exist raises: a sharded run on
+    fewer chips than asked for would report numbers for a mesh nobody
+    requested."""
     n = len(jax.devices())
-    want = (data, model)
-    data = max(1, min(data, n))
-    model = max(1, min(model, n // data))
-    if (data, model) != want:
-        warnings.warn(
-            f"requested mesh (data, model)={want} clamped to "
-            f"({data}, {model}): only {n} device(s) available",
-            stacklevel=2)
-    return compat_make_mesh((data, model), ("data", "model"))
+    if data * model > n:
+        raise ValueError(f"mesh (data, model)=({data}, {model}) needs "
+                         f"{data * model} devices; only {n} available")
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def parse_mesh(flag: str) -> Tuple[int, int]:

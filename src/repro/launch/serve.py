@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models import lm
+from .compile_cache import enable_compile_cache
 from .engine import EngineConfig, Request, sample_logits
 
 
@@ -165,6 +166,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main():
     args = build_parser().parse_args()
+    enable_compile_cache()
     _resolve_deprecated(args, "batch", "requests")
     _resolve_deprecated(args, "gen", "max_new_tokens")
     n_req = 4 if args.requests is None else args.requests
@@ -179,8 +181,8 @@ def main():
         seed=args.seed).build()
     cfg, packed = engine.cfg, engine.packed
     served = engine.serve_params
-    # the mesh that actually runs (make_host_mesh clamps to the device
-    # count), so the smoke tok/s numbers below are attributable
+    # the mesh that actually runs, so the tok/s numbers below are
+    # attributable
     print(f"[serve] mesh: {dict(engine.mesh.shape)} over "
           f"{len(jax.devices())} device(s)")
     prompts = jax.random.randint(engine.prompt_key, (n_req, args.prompt_len),

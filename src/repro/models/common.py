@@ -28,24 +28,6 @@ def get_mesh() -> Optional[jax.sharding.Mesh]:
     return _CURRENT_MESH
 
 
-def compat_shard_map(f, *, mesh, in_specs, out_specs):
-    """shard_map across jax versions: the top-level jax.shard_map only
-    exists from jax 0.5, and its replication-check kwarg was renamed
-    check_rep -> check_vma along the way — feature-detect both."""
-    import inspect
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        kwargs_ok = set(inspect.signature(sm).parameters)
-    except (TypeError, ValueError):                      # pragma: no cover
-        kwargs_ok = {"check_vma"}
-    check = {"check_vma": False} if "check_vma" in kwargs_ok else \
-        {"check_rep": False} if "check_rep" in kwargs_ok else {}
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **check)
-
-
 def clean_spec(*spec) -> P:
     """PartitionSpec with axes absent from the current mesh dropped."""
     mesh = _CURRENT_MESH

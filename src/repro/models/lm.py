@@ -216,7 +216,8 @@ def shard_params(params: Dict[str, Any], cfg: ModelConfig,
     """Lay a (possibly prepacked) param tree out on ``mesh`` for serving:
     plan placements where the config carries them, the bit-exact serving
     defaults elsewhere.  Axes that do not divide their dim degrade to
-    replicated (constrained_sharding) instead of crashing."""
+    replicated (constrained_sharding) instead of crashing.  Leaves already
+    in that layout are returned as they are, not copied."""
     specs = param_specs(cfg, jax.eval_shape(lambda: params), serving=True)
     # params leads the tree.map, so each of its array leaves picks up the
     # corresponding PartitionSpec from the specs tree whole

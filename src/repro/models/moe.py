@@ -33,8 +33,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..core.layers import apply_linear, init_linear
-from .common import (act_fn, compat_shard_map, get_mesh, shard, BATCH_AXES,
-                     TENSOR_AXIS)
+from .common import act_fn, get_mesh, shard, BATCH_AXES, TENSOR_AXIS
 from .config import ModelConfig
 
 Array = jax.Array
@@ -177,8 +176,8 @@ def moe_dispatch(params: dict, x: Array, cfg: ModelConfig) -> Array:
         out = _local_unpack(back, info, x2.shape[0], d)
         return out.reshape(Bl, S, d).astype(x_l.dtype)
 
-    return compat_shard_map(
-        local_fn, mesh=mesh,
+    return jax.shard_map(
+        local_fn, mesh=mesh, check_vma=False,
         in_specs=(P(dp_axes, None, None), P(None, None),
                   P(dp_axes, None, TENSOR_AXIS), P(dp_axes, None, TENSOR_AXIS),
                   P(dp_axes, TENSOR_AXIS, None)),

@@ -479,12 +479,15 @@ def pack_grid(spec: EpitomeSpec, tile: int = 256) -> Tuple[int, int]:
     builds QuantConfigs at the 256 default) — the planner must know the
     grid shape to snap ``scales='shard'`` placements without importing the
     kernel stack; a cross-check test guards against drift.  Mirrors
-    ``_pick_bk_quant``'s prime/odd-m fallback: the largest standard block
-    not exceeding min(tile, m) when nothing divides m exactly."""
-    blocks = (256, 128, 64, 32, 16, 8)
-    bk = next((b for b in blocks if b <= tile and spec.m % b == 0), None)
-    if bk is None:
-        bk = next((b for b in blocks if b <= min(tile, spec.m)), spec.m)
+    ``_pick_k_block``: an m under the tile is one whole block; otherwise
+    the 128-multiple block (or, for tiles under 128, the largest narrow
+    block) that pads m least, the larger on a tie."""
+    if spec.m <= tile:
+        bk = spec.m
+    else:
+        menu = [b for b in (512, 256, 128) if b <= tile] or \
+            [b for b in (64, 32, 16, 8) if b <= tile][:1]
+        bk = min(menu, key=lambda b: (-(-spec.m // b) * b, -b))
     return -(-spec.m // bk), -(-spec.n // spec.bn)
 
 

@@ -133,6 +133,18 @@ class TestTune:
                            cache_dir=str(tmp_path))
         assert r2.source == "timed"
 
+    def test_failing_baseline_raises(self, tmp_path, monkeypatch):
+        """A heuristic-blocks kernel that fails to build or run is a fault
+        to surface, not a tuning result: tune raises instead of returning
+        source='heuristic'."""
+        def broken(*a, **k):
+            raise RuntimeError("kernel refused")
+        monkeypatch.setattr(ops, "quant_epitome_matmul", broken)
+        with pytest.raises(RuntimeError, match="kernel refused"):
+            autotune.tune(SPEC, 3, 8, grid="tiny", timer=CountingTimer(),
+                          cache_dir=str(tmp_path))
+        assert not os.listdir(tmp_path)          # nothing cached
+
     def test_winner_bit_identical_and_accurate(self, tmp_path):
         """The default contract: the winning blocks produce output
         bit-identical to the heuristic blocks, within 1e-4 of the

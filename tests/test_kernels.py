@@ -246,18 +246,26 @@ class TestPickBkAndOverrides:
     PRIME_M = dict(M=512, N=512, m=251, n=256, bm=128, bn=256)
 
     def test_pick_bk_no_degenerate_blocks(self):
-        for m in (8, 16, 97, 193, 251, 256, 1000, 1024):
+        """Every bk is Mosaic-legal on the lane dim — a multiple of 128 or
+        the whole m — and pads m by less than one block."""
+        for m in (8, 16, 97, 193, 251, 256, 1000, 1024, 1100):
             bk = ops._pick_bk(m)
+            assert bk == m or bk % 128 == 0, (m, bk)
             assert 8 <= bk <= max(m, 8), (m, bk)
             assert (-m) % bk < bk
         assert ops._pick_bk(1024) == 512         # exact divisor preferred
-        assert ops._pick_bk(251) == 128          # prime: largest block <= m
+        assert ops._pick_bk(251) == 251          # fits: one whole block
+        assert ops._pick_bk(1100) == 128         # least padding: 1152 rows
+        assert ops._pick_bk(1000) == 512         # tie on 1024 rows: larger
 
     def test_pick_bk_quant_respects_tile(self):
         assert ops._pick_bk_quant(1024, 256) == 256
-        assert ops._pick_bk_quant(251, 256) == 128   # prime m
+        assert ops._pick_bk_quant(251, 256) == 251   # prime m under the tile
+        assert ops._pick_bk_quant(288, 256) == 128   # ResNet-50 3x3 conv
+        assert ops._pick_bk_quant(576, 256) == 128   # pads to 640, not 768
+        assert ops._pick_bk_quant(2000, 256) == 256  # both pad to 2048
         assert ops._pick_bk_quant(97, 64) == 64      # capped by tile
-        assert ops._pick_bk_quant(4, 256) == 4       # tiny m fallback
+        assert ops._pick_bk_quant(4, 256) == 4       # tiny m: whole dim
 
     def test_prime_m_fp_kernel(self):
         spec = EpitomeSpec(**self.PRIME_M)
@@ -373,3 +381,122 @@ class TestPickBt:
                                        interpret=True)
         assert y_q.shape == (T, spec.N)
         assert bool(jnp.all(jnp.isfinite(y_q)))
+
+
+class TestSmemTablesAndLaneBlocks:
+    """The int8 kernels read their per-block (scale, zero) tables whole
+    from SMEM, indexed [k, cb[j]], and take lane-legal contraction blocks
+    (a multiple of 128 or the whole m) — checked against the ref.py
+    oracles in interpret mode."""
+
+    @staticmethod
+    def _tables(gk, gn, seed=0):
+        # a distinct (s, z) per block, so any mis-indexed read shows up
+        ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+        s = jax.random.uniform(ks[0], (gk, gn), minval=1e-3, maxval=1e-2)
+        z = jnp.round(jax.random.uniform(ks[1], (gk, gn), minval=-4,
+                                         maxval=4))
+        return s, z
+
+    @pytest.mark.parametrize("cb", [[0, 1, 2], [2, 0, 2, 1], [1, 1]])
+    def test_blocks_kernel_vs_oracle(self, cb):
+        from repro.kernels.quant_epitome_matmul import \
+            quant_epitome_matmul_blocks
+        from repro.kernels.ref import quant_epitome_matmul_blocks_ref
+        m, n, bk, bn = 384, 384, 128, 128
+        q = jax.random.randint(KEY, (m, n), -4, 4).astype(jnp.int8)
+        s, z = self._tables(m // bk, n // bn)
+        x = jax.random.normal(jax.random.PRNGKey(1), (16, m))
+        y = quant_epitome_matmul_blocks(x, q, s, z, np.array(cb), bt=8,
+                                        bk=bk, bn=bn, interpret=True)
+        ref = quant_epitome_matmul_blocks_ref(x, q, s, z, np.array(cb),
+                                              bk, bn)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
+
+    def test_fused_fold_kernel_vs_oracle(self):
+        """The fused-fold variant reads the same SMEM tables; its output
+        agrees with fold_rows + the block oracle."""
+        from repro.kernels.quant_epitome_matmul import \
+            quant_epitome_matmul_fused_fold
+        from repro.kernels.ref import quant_epitome_matmul_blocks_ref
+        spec = EpitomeSpec(M=768, N=512, m=384, n=256, bm=256, bn=128)
+        bk, bn = 128, 128
+        q = jax.random.randint(KEY, (spec.m, spec.n), -4, 4).astype(jnp.int8)
+        s, z = self._tables(spec.m // bk, spec.n // bn, seed=2)
+        x = jax.random.normal(jax.random.PRNGKey(3), (8, spec.M))
+        cb = ops.kernel_col_blocks(spec, bn)
+        y = quant_epitome_matmul_fused_fold(
+            x.T, q, s, z, cb, spec.row_offsets(), bm=spec.bm, bt=8, bk=bk,
+            bn=bn, interpret=True)
+        ref = quant_epitome_matmul_blocks_ref(ops.fold_rows(x, spec), q, s,
+                                              z, cb, bk, bn)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("m,bk", [(288, 128), (576, 128), (2000, 256)])
+    def test_padded_lane_blocks_vs_reconstruct(self, m, bk):
+        """Epitome row counts that no lane-legal block divides (ResNet-50's
+        288 and 576, its fc's 2000) pad m up to a 128-multiple block and
+        still equal x @ W(deq(Q(E)))."""
+        from repro.core.quant import QuantConfig, dequantize_packed
+        spec = EpitomeSpec(M=2 * m, N=256, m=m, n=256, bm=min(256, m),
+                           bn=128)
+        qcfg = QuantConfig(bits=3)
+        E = jax.random.normal(KEY, (spec.m, spec.n))
+        p = ops.pack_epitome(E, spec, qcfg)
+        assert p.bk == bk and p.scales.shape == (-(-m // bk), 2)
+        x = jax.random.normal(jax.random.PRNGKey(5), (12, spec.M))
+        W = reconstruct(dequantize_packed(p.q, p.scales, p.zeros,
+                                          (p.bk, p.bn)), spec)
+        for fused in (False, True):
+            y = ops.quant_epitome_matmul(x, None, spec, packed=p,
+                                         fused_fold=fused, interpret=True)
+            np.testing.assert_allclose(np.asarray(y), np.asarray(x @ W),
+                                       rtol=1e-4, atol=1e-4)
+
+    def test_fused_fold_default_bt_is_lane_legal(self, monkeypatch):
+        """The fused-fold kernel's bt is the lane dim of its activation
+        block: the whole (8-padded) T up to 128 rows, 128 beyond."""
+        from repro.core.quant import QuantConfig
+        spec = EpitomeSpec(**ALIGNED)
+        seen = []
+
+        def spy(xt, *a, bt, **k):
+            seen.append((xt.shape[1], bt))
+            return jnp.zeros((xt.shape[1], spec.N), jnp.float32)
+        monkeypatch.setattr(ops, "quant_epitome_matmul_fused_fold", spy)
+        p = ops.pack_epitome(jnp.ones((spec.m, spec.n)), spec,
+                             QuantConfig(bits=3))
+        for T in (4, 100, 300):
+            ops.quant_epitome_matmul(jnp.ones((T, spec.M)), None, spec,
+                                     packed=p, fused_fold=True,
+                                     interpret=True)
+        assert seen == [(8, 8), (104, 104), (384, 128)]
+
+
+class TestInterpretDecidedPerCall:
+    def test_follows_the_backend_at_call_time(self, monkeypatch):
+        assert ops.interpret_mode() == (jax.default_backend() == "cpu")
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert ops.interpret_mode() is False
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+        assert ops.interpret_mode() is True
+        assert ops.interpret_mode(False) is False     # explicit wins
+
+    def test_wrappers_ask_at_call_time(self, monkeypatch):
+        """The public wrappers consult the backend on every call (not a
+        flag frozen at import), so a process that reaches the chip never
+        runs a kernel in interpret mode."""
+        seen = []
+
+        def spy(folded, E, cb, *, bt, bk, bn, interpret):
+            seen.append(interpret)
+            return jnp.zeros((folded.shape[0], len(cb) * bn), folded.dtype)
+        monkeypatch.setattr(ops, "epitome_matmul_blocks", spy)
+        spec = EpitomeSpec(M=256, N=256, m=128, n=256, bm=128, bn=256)
+        x, E = jnp.ones((8, spec.M)), jnp.ones((spec.m, spec.n))
+        for backend in ("tpu", "cpu"):
+            monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+            ops.epitome_matmul(x, E, spec)
+        assert seen == [False, True]

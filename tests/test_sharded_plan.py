@@ -167,19 +167,39 @@ def test_plan_run_mesh_cli():
 
 
 # ---------------------------------------------------------------------------
-# In-process (fast): mesh clamp warning + placement fallback warnings
+# In-process (fast): oversized mesh refusal + placement fallback warnings
 # ---------------------------------------------------------------------------
 def test_make_host_mesh_warns_on_clamp():
+    """An explicit mesh larger than the device count is refused (it used
+    to be clamped with a warning, so a run could report numbers for a mesh
+    nobody asked for); one that fits builds silently."""
     import jax
     from repro.launch.mesh import make_host_mesh
     n = len(jax.devices())
-    with pytest.warns(UserWarning, match="clamped"):
-        mesh = make_host_mesh(data=n + 1, model=1)
-    assert mesh.shape["data"] <= n
+    for data, model in ((n + 1, 1), (1, n + 1), (n, 2)):
+        with pytest.raises(ValueError, match="needs"):
+            make_host_mesh(data=data, model=model)
 
     with warnings.catch_warnings():
         warnings.simplefilter("error")          # no warning when it fits
-        make_host_mesh(data=1, model=1)
+        mesh = make_host_mesh(data=1, model=1)
+    assert dict(mesh.shape) == {"data": 1, "model": 1}
+
+
+def test_engine_explicit_mesh_too_large_raises():
+    """EngineConfig(mesh='D,M') goes through make_host_mesh: an explicit
+    sharded layout that the devices cannot hold fails before any param is
+    built, while the automatic data-parallel default still fits."""
+    import jax
+    from repro.launch.engine import EngineConfig
+    from repro.models.common import set_mesh
+    n = len(jax.devices())
+    try:
+        with pytest.raises(ValueError, match="needs"):
+            EngineConfig(arch="rwkv6-7b", smoke=True,
+                         mesh=f"1,{n + 1}").build()
+    finally:
+        set_mesh(None)
 
 
 def test_mesh_for_plan_warns_on_placement_fallback():

@@ -36,8 +36,9 @@ def test_moe_dispatch_matches_dense():
         from repro.configs import get_smoke_config
         from repro.models import moe
         from repro.models.common import set_mesh
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((4, 2), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+            axis_types=(AxisType.Auto,) * 2)
         set_mesh(mesh)
         cfg = dataclasses.replace(get_smoke_config("phi3.5-moe-42b-a6.6b"),
                                   capacity_factor=8.0,
@@ -64,8 +65,9 @@ def test_moe_grok_replicated_experts():
         from repro.configs import get_smoke_config
         from repro.models import moe
         from repro.models.common import set_mesh
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((8, 1), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((8, 1), ("data", "model"),
+            axis_types=(AxisType.Auto,) * 2)
         set_mesh(mesh)
         cfg = dataclasses.replace(get_smoke_config("grok-1-314b"),
                                   capacity_factor=8.0,
@@ -96,8 +98,9 @@ def test_sharded_train_step_runs():
         from repro.train.loop import TrainConfig, init_state, make_train_step
         from repro.train.optimizer import AdamWConfig
         from repro.train.data import SyntheticData
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((4, 2), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+            axis_types=(AxisType.Auto,) * 2)
         set_mesh(mesh)
         cfg = get_smoke_config("qwen2-72b")
         opt = AdamWConfig(lr=1e-3, total_steps=5)
@@ -127,8 +130,9 @@ def test_mini_multipod_dryrun():
         from repro.models.common import set_mesh, clean_spec
         from repro.train.loop import TrainConfig, init_state, make_train_step
         from repro.train.optimizer import AdamWConfig
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+            axis_types=(AxisType.Auto,) * 3)
         set_mesh(mesh)
         cfg = get_smoke_config("jamba-1.5-large-398b")
         opt = AdamWConfig(moments_dtype="int8")
@@ -165,14 +169,16 @@ def test_elastic_restart_new_mesh():
         from jax.sharding import NamedSharding
         cfg = get_smoke_config("deepseek-67b")
         d = tempfile.mkdtemp()
-        from repro.launch.mesh import compat_make_mesh
-        mesh1 = compat_make_mesh((4, 2), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh1 = jax.make_mesh((4, 2), ("data", "model"),
+            axis_types=(AxisType.Auto,) * 2)
         set_mesh(mesh1)
         params = lm.init_params(jax.random.PRNGKey(0), cfg)
         mgr = CheckpointManager(d, async_write=False)
         mgr.save(1, params, blocking=True)
         # "restart" on a different layout
-        mesh2 = compat_make_mesh((2, 4), ("data", "model"))
+        mesh2 = jax.make_mesh((2, 4), ("data", "model"),
+            axis_types=(AxisType.Auto,) * 2)
         set_mesh(mesh2)
         specs = lm.param_specs(cfg, jax.eval_shape(lambda: params))
         from repro.models.common import clean_spec
@@ -184,3 +190,41 @@ def test_elastic_restart_new_mesh():
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
         print("ELASTIC RESTORE OK")
     """)
+
+
+def test_compile_cache_placed_from_outside_or_in_checkout(monkeypatch,
+                                                          tmp_path):
+    """enable_compile_cache leaves an outside JAX_COMPILATION_CACHE_DIR to
+    JAX and otherwise points the cache at the fixed in-checkout path."""
+    import jax
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert compile_cache.enable_compile_cache() == \
+            compile_cache.CACHE_DIR == os.path.join(REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == \
+            compile_cache.CACHE_DIR
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_files_land_in_outside_dir(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, an entry point's compiles are
+    written there (JAX reads the variable itself)."""
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               PYTHONPATH=os.path.join(REPO, "src"))
+    code = textwrap.dedent("""
+        import jax, jax.numpy as jnp
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
+        jax.jit(lambda x: jnp.sin(x) * 2)(jnp.ones(8)).block_until_ready()
+    """)
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=300, env=env)
+    assert out.returncode == 0, out.stderr
+    assert os.listdir(tmp_path), "no cache entry written"
