@@ -328,13 +328,16 @@ def effective_weight(params: dict, cfg: EpLayerConfig) -> Array:
     return W
 
 
+@jax.named_scope("epim.epitome_matmul")
 def _dispatch_epitome_matmul(params: dict, x: Array, cfg: EpLayerConfig) -> Array:
     """(…, M) @ W(E) -> (…, N) through the full mode x quant matrix.
 
     The single execution ladder shared by linear layers and (via their
     im2col patch matrix) convolutions: reconstruct | wrapped | folded |
     kernel | kernel x quant, each composed with fake or packed-int8
-    quantization as documented in the module docstring."""
+    quantization as documented in the module docstring.  Every device op
+    it emits carries ``epim.epitome_matmul`` in its op_name, which is how
+    a profile's reduction finds the epitome layers' device time."""
     E = params["E"]
     if cfg.mode == "kernel":
         # import here to keep layers importable without pallas

@@ -81,4 +81,6 @@ def epitome_matmul_blocks(x_folded: Array, E: Array, col_blocks,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="epim_epitome_matmul",
+        metadata={"epim_kernel": "epim_epitome_matmul"},
     )(col_blocks, x_folded, E)

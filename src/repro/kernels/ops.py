@@ -65,8 +65,11 @@ def col_blocks_splittable(spec: EpitomeSpec, bn: int) -> bool:
             and bool((spec.col_offsets() % bn == 0).all()))
 
 
+@jax.named_scope("epim.fold")
 def fold_rows(x: jax.Array, spec: EpitomeSpec) -> jax.Array:
-    """IFRT analogue: scatter-add virtual fan-in into epitome rows."""
+    """IFRT analogue: scatter-add virtual fan-in into epitome rows.  Its
+    device ops carry ``epim.fold`` in their op_name (profile reductions
+    group the fold's time by it)."""
     rmap = jnp.asarray(spec.row_index_map())
     xt = jnp.moveaxis(x, -1, 0)
     folded = jax.ops.segment_sum(xt, rmap, num_segments=spec.m)

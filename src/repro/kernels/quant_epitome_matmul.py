@@ -115,6 +115,8 @@ def quant_epitome_matmul_blocks(x_folded: Array, q: Array, scales: Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="epim_qmm",
+        metadata={"epim_kernel": "epim_qmm"},
     )(col_blocks, x_folded, q, scales, zeros)
 
 
@@ -195,4 +197,6 @@ def quant_epitome_matmul_fused_fold(xt: Array, q: Array, scales: Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="epim_qmm_fused_fold",
+        metadata={"epim_kernel": "epim_qmm_fused_fold"},
     )(col_blocks, row_offsets, xt, q, scales, zeros)

@@ -63,4 +63,6 @@ def quant_matmul(x: Array, q: Array, scales: Array, zeros: Array,
         out_shape=jax.ShapeDtypeStruct((T, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bt, TILE), jnp.float32)],
         interpret=interpret,
+        name="epim_quant_matmul",
+        metadata={"epim_kernel": "epim_quant_matmul"},
     )(x, q, scales, zeros)

@@ -75,4 +75,6 @@ def wkv6_chunked(r: Array, k: Array, v: Array, logw: Array, u: Array,
         out_shape=jax.ShapeDtypeStruct((BH, S, K), r.dtype),
         scratch_shapes=[pltpu.VMEM((K, K), jnp.float32)],
         interpret=interpret,
+        name="epim_wkv6",
+        metadata={"epim_kernel": "epim_wkv6"},
     )(r, k, v, logw, u)

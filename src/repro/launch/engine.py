@@ -23,7 +23,8 @@ API reference
     serving benchmark derives inter-token decode gaps from these).
 
 ``RequestHandle``
-    Returned by ``submit``; ``done()`` / ``result()`` poll the completion.
+    Returned by ``submit``; ``done()`` / ``result()`` poll the completion;
+    ``token_times`` gives the per-token stamps so far, finished or not.
 
 ``EngineConfig``
     Dataclass of everything needed to stand a server up: ``arch``,
@@ -48,9 +49,12 @@ API reference
     and returns how many tokens were emitted; ``drain()`` steps until
     idle and returns every completion in submission order.  ``stats``
     counts ``prefill_traces`` / ``prefill_chunks`` / ``slot_reuses`` /
-    ``decode_steps`` / ``completed`` / ``admitted``, plus occupancy:
-    ``queue_depth``, ``slot_hwm``, and the pool's ``pages_total`` /
-    ``pages_used`` / ``pages_free`` / ``pages_hwm`` / ``page_reuses``.
+    ``decode_steps`` / ``decode_micro_steps`` / ``decode_traces`` /
+    ``completed`` / ``admitted``, and ``prefill_s``: host seconds from
+    each prefill call until its first token is on the host (see
+    Tracing); plus occupancy: ``queue_depth`` and the pool's
+    ``pages_total`` / ``pages_used`` / ``pages_free`` / ``pages_hwm`` /
+    ``page_reuses``.
 
 Scheduling model
 ----------------
@@ -117,9 +121,29 @@ the same rows dense attention reads in place; decode rows are
 independent so batch width doesn't perturb a request; and
 ``jax.random.categorical`` over a ``(V,)`` row draws the same bits as
 over ``(1, V)`` (flat threefry counter reshape).
+
+Tracing
+-------
+The engine marks its layer boundaries with ``jax.profiler`` host spans,
+which land in the same profile as the device's ops, on one clock; they
+cost a few objects per step when no profile is recording.  ``epim.step``
+(a step annotation numbered by ``decode_steps``) wraps ``step()``;
+``epim.submit`` wraps ``submit()``; ``epim.admit`` (``rid``, ``slot``)
+an admission; ``epim.prefill`` (``rid`` and ``bucket``, or ``chunk``) a
+prefill call up to its first token on the host, with two children: the
+launches that activate it (``epim.activate``: the scatter into the pool
+and the carry poke) and the blocking read of that token
+(``epim.prefill.wait``); ``epim.retire`` the
+bookkeeping of a finished dispatch, with ``epim.retire.wait`` around the
+blocking read of its tokens; ``epim.dispatch`` (``k``, ``live`` slots)
+the launch of the next one.  ``stats["prefill_s"]`` adds up the host
+time of the ``epim.prefill`` spans, traced or not; an intermediate chunk
+of a chunked prefill counts only its launch, since the engine never
+waits on it.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import itertools
@@ -140,15 +164,14 @@ from .mesh import make_host_mesh, mesh_for_plan, parse_mesh
 
 # Python-side counter bumped inside the jitted prefill bodies: it only
 # fires when XLA (re)traces, so deltas count compiled prefill programs.
-# The module hook exists for the trace-bound tests; engines attribute
-# deltas around their OWN prefill calls to a per-engine counter
-# (stats["prefill_traces"]), so two engines in one process no longer
-# corrupt each other's numbers.
+# Engines attribute the deltas around their OWN prefill calls to a
+# per-engine counter (stats["prefill_traces"]), so two engines in one
+# process do not corrupt each other's numbers.
 PREFILL_TRACES = [0]
 
-# Same idea for the fused decode macro-step: one compiled program per
-# (cfg, K) — deltas bound how many K values the auto-pick rule visited,
-# NOT how many requests were served.
+# Same idea for the fused decode macro-step (stats["decode_traces"]): one
+# compiled program per (cfg, K) — deltas bound how many K values the
+# auto-pick rule visited, NOT how many requests were served.
 DECODE_TRACES = [0]
 
 
@@ -212,6 +235,12 @@ class RequestHandle:
             raise RuntimeError(f"request {self._rec.rid} not finished; "
                                "step()/drain() the engine first")
         return self._rec.completion
+
+    @property
+    def token_times(self) -> Tuple[float, ...]:
+        """perf_counter stamp of every token emitted so far, finished or
+        not (a finished request's equal its Completion's)."""
+        return tuple(self._rec.token_times)
 
 
 class _Inflight:
@@ -469,11 +498,11 @@ class EpimEngine:
         self._pending: deque = deque()
         self._records: List[_Record] = []
         self._next_id = itertools.count()
-        self._slot_hwm = 0
         self._stats = {"slot_reuses": 0, "decode_steps": 0,
                        "decode_micro_steps": 0, "decode_traces": 0,
                        "completed": 0, "admitted": 0,
-                       "prefill_traces": 0, "prefill_chunks": 0}
+                       "prefill_traces": 0, "prefill_chunks": 0,
+                       "prefill_s": 0.0}
         # set by EngineConfig.build (None for a bare-constructed engine)
         self.config: Optional[EngineConfig] = None
         self.mesh = None
@@ -481,6 +510,7 @@ class EpimEngine:
         self.prompt_key = self.sample_key = None
 
     # -- public API ---------------------------------------------------------
+    @functools.partial(jax.profiler.annotate_function, name="epim.submit")
     def submit(self, request: Request) -> RequestHandle:
         P = len(request.prompt)
         if P < 1:
@@ -525,14 +555,16 @@ class EpimEngine:
         arrays directly, so the only host<->device traffic per tick is
         the small stacked-token download at retire — and that download
         happens after the next macro-step is already enqueued."""
-        self._chunks_left = 1
-        if self._prefilling is not None:
-            self._advance_prefill()
-        self._admit_all()
-        emitted = self._retire()
-        self._admit_all()                  # slots/pages freed by _retire
-        self._dispatch()
-        return emitted
+        with jax.profiler.StepTraceAnnotation(
+                "epim.step", step_num=self._stats["decode_steps"]):
+            self._chunks_left = 1
+            if self._prefilling is not None:
+                self._advance_prefill()
+            self._admit_all()
+            emitted = self._retire()
+            self._admit_all()              # slots/pages freed by _retire
+            self._dispatch()
+            return emitted
 
     def drain(self) -> List[Completion]:
         """Step until no request is pending, prefilling, active, or in
@@ -545,10 +577,9 @@ class EpimEngine:
                 if r.completion is not None]
 
     @property
-    def stats(self) -> Dict[str, int]:
+    def stats(self) -> Dict[str, float]:
         return {**self._stats,
                 "queue_depth": len(self._pending),
-                "slot_hwm": self._slot_hwm,
                 **self._pool.stats()}
 
     @property
@@ -577,26 +608,28 @@ class EpimEngine:
         if not self._active or self._inflight is not None:
             return
         k = self._pick_k()
-        remaining = np.zeros((self.capacity,), np.int32)
-        snapshot = []
-        for slot, rec in self._active.items():
-            r = rec.request.max_new_tokens - len(rec.tokens)
-            remaining[slot] = r
-            snapshot.append((slot, rec, min(k, r)))
-        base = DECODE_TRACES[0]
-        toks, live, tree, tok, keys = _decode_multi(
-            self.serve_params, self._pool.tree, self._tok,
-            jnp.asarray(self._pos), self._key, jnp.asarray(self._temp),
-            jnp.asarray(remaining), self._pool.page_table,
-            cfg=self.cfg, k=k)
-        self._stats["decode_traces"] += DECODE_TRACES[0] - base
-        self._pool.tree = tree
-        self._tok, self._key = tok, keys
-        for slot, _, n in snapshot:
-            self._pos[slot] += n
-        self._stats["decode_steps"] += 1
-        self._stats["decode_micro_steps"] += k
-        self._inflight = _Inflight(toks, snapshot, k)
+        with jax.profiler.TraceAnnotation("epim.dispatch", k=k,
+                                          live=len(self._active)):
+            remaining = np.zeros((self.capacity,), np.int32)
+            snapshot = []
+            for slot, rec in self._active.items():
+                r = rec.request.max_new_tokens - len(rec.tokens)
+                remaining[slot] = r
+                snapshot.append((slot, rec, min(k, r)))
+            base = DECODE_TRACES[0]
+            toks, live, tree, tok, keys = _decode_multi(
+                self.serve_params, self._pool.tree, self._tok,
+                jnp.asarray(self._pos), self._key, jnp.asarray(self._temp),
+                jnp.asarray(remaining), self._pool.page_table,
+                cfg=self.cfg, k=k)
+            self._stats["decode_traces"] += DECODE_TRACES[0] - base
+            self._pool.tree = tree
+            self._tok, self._key = tok, keys
+            for slot, _, n in snapshot:
+                self._pos[slot] += n
+            self._stats["decode_steps"] += 1
+            self._stats["decode_micro_steps"] += k
+            self._inflight = _Inflight(toks, snapshot, k)
 
     def _retire(self) -> int:
         """Block on the in-flight macro-step's stacked tokens and do the
@@ -608,17 +641,19 @@ class EpimEngine:
         inf, self._inflight = self._inflight, None
         if inf is None:
             return 0
-        toks = np.asarray(jax.device_get(inf.toks))   # (k, C)
-        now = time.perf_counter()
-        emitted = 0
-        for slot, rec, n in inf.snapshot:
-            for j in range(n):
-                rec.tokens.append(int(toks[j, slot]))
-                rec.token_times.append(now)
-            emitted += n
-            if len(rec.tokens) >= rec.request.max_new_tokens:
-                self._finish(rec)
-        return emitted
+        with jax.profiler.TraceAnnotation("epim.retire"):
+            with jax.profiler.TraceAnnotation("epim.retire.wait"):
+                toks = np.asarray(jax.device_get(inf.toks))   # (k, C)
+            now = time.perf_counter()
+            emitted = 0
+            for slot, rec, n in inf.snapshot:
+                for j in range(n):
+                    rec.tokens.append(int(toks[j, slot]))
+                    rec.token_times.append(now)
+                emitted += n
+                if len(rec.tokens) >= rec.request.max_new_tokens:
+                    self._finish(rec)
+            return emitted
 
     def _bucket(self, P: int) -> int:
         if not self.bucket_prompts:
@@ -647,29 +682,44 @@ class EpimEngine:
 
     def _admit(self, rec: _Record) -> None:
         slot = self._free.pop()
-        self._stats["slot_reuses"] += slot in self._used
-        self._used.add(slot)
-        rec.slot = slot
-        self._slot_hwm = max(self._slot_hwm, self.capacity - len(self._free))
-        req = rec.request
-        P = len(req.prompt)
-        self._pool.alloc(slot, P + req.max_new_tokens)
-        rec.queue_wait = time.perf_counter() - rec.submit_t
-        if self._needs_chunking(P):
-            state = _fresh_chunk_state(cfg=self.cfg, seq_len=self.seq_len)
-            self._prefilling = _PrefillJob(rec, state)
-            self._advance_prefill()
-            return
-        L = self._bucket(P)
-        prompt = np.zeros((1, L), np.int32)
-        prompt[0, :P] = req.prompt
-        base = PREFILL_TRACES[0]
-        tok, key, state = _prefill_one(
-            self.serve_params, jnp.asarray(prompt), jnp.int32(P),
-            jax.random.PRNGKey(req.seed), jnp.float32(req.temperature),
-            cfg=self.cfg, max_len=self.seq_len)
-        self._stats["prefill_traces"] += PREFILL_TRACES[0] - base
-        self._activate(rec, state, tok, key)
+        with jax.profiler.TraceAnnotation("epim.admit", rid=rec.rid,
+                                          slot=slot):
+            self._stats["slot_reuses"] += slot in self._used
+            self._used.add(slot)
+            rec.slot = slot
+            req = rec.request
+            P = len(req.prompt)
+            self._pool.alloc(slot, P + req.max_new_tokens)
+            rec.queue_wait = time.perf_counter() - rec.submit_t
+            if self._needs_chunking(P):
+                state = _fresh_chunk_state(cfg=self.cfg,
+                                           seq_len=self.seq_len)
+                self._prefilling = _PrefillJob(rec, state)
+                self._advance_prefill()
+                return
+            L = self._bucket(P)
+            prompt = np.zeros((1, L), np.int32)
+            prompt[0, :P] = req.prompt
+            with self._prefill_span(rec, bucket=L):
+                base = PREFILL_TRACES[0]
+                tok, key, state = _prefill_one(
+                    self.serve_params, jnp.asarray(prompt), jnp.int32(P),
+                    jax.random.PRNGKey(req.seed),
+                    jnp.float32(req.temperature),
+                    cfg=self.cfg, max_len=self.seq_len)
+                self._stats["prefill_traces"] += PREFILL_TRACES[0] - base
+                self._activate(rec, state, tok, key)
+
+    @contextlib.contextmanager
+    def _prefill_span(self, rec: _Record, **where):
+        """``epim.prefill`` around one prefill call (and, for the last, its
+        activation up to the first token on the host), its host seconds
+        added to ``stats["prefill_s"]``."""
+        t = time.perf_counter()
+        with jax.profiler.TraceAnnotation("epim.prefill", rid=rec.rid,
+                                          **where):
+            yield
+        self._stats["prefill_s"] += time.perf_counter() - t
 
     def _advance_prefill(self) -> None:
         """Run ONE chunk of the in-flight chunked prefill (if any and if
@@ -684,30 +734,38 @@ class EpimEngine:
         n = min(self.chunk, P - lo)
         buf = np.zeros((1, self.chunk), np.int32)
         buf[0, :n] = req.prompt[lo:lo + n]
-        base = PREFILL_TRACES[0]
-        logits, job.state = _prefill_chunk(
-            self.serve_params, jnp.asarray(buf), job.state, jnp.int32(lo),
-            jnp.int32(n), cfg=self.cfg)
-        self._stats["prefill_traces"] += PREFILL_TRACES[0] - base
-        self._stats["prefill_chunks"] += 1
-        job.done = lo + n
-        if job.done >= P:
+        with self._prefill_span(job.rec, chunk=lo // self.chunk):
+            base = PREFILL_TRACES[0]
+            logits, job.state = _prefill_chunk(
+                self.serve_params, jnp.asarray(buf), job.state,
+                jnp.int32(lo), jnp.int32(n), cfg=self.cfg)
+            self._stats["prefill_traces"] += PREFILL_TRACES[0] - base
+            self._stats["prefill_chunks"] += 1
+            job.done = lo + n
+            if job.done < P:
+                return
             tok, key = _first_token(logits, jax.random.PRNGKey(req.seed),
                                     jnp.float32(req.temperature))
             self._prefilling = None
             self._activate(job.rec, job.state, tok, key)
 
     def _activate(self, rec: _Record, state, tok, key) -> None:
-        """Scatter a finished prefill into the pool and go live."""
+        """Scatter a finished prefill into the pool, poke its first token
+        and key into the decode carries, and go live once that token is
+        on the host.  Both launches precede the wait, so the device runs
+        them straight after the prefill while the host waits."""
         slot = rec.slot
         req = rec.request
-        self._pool.scatter(slot, state)
-        rec.tokens.append(int(jax.device_get(tok)))
+        with jax.profiler.TraceAnnotation("epim.activate", rid=rec.rid,
+                                          slot=slot):
+            self._pool.scatter(slot, state)
+            self._tok, self._key = _poke_slot(self._tok, self._key,
+                                              jnp.int32(slot), tok, key)
+        with jax.profiler.TraceAnnotation("epim.prefill.wait"):
+            rec.tokens.append(int(jax.device_get(tok)))
         now = time.perf_counter()
         rec.first_tok_t = now
         rec.token_times.append(now)
-        self._tok, self._key = _poke_slot(self._tok, self._key,
-                                          jnp.int32(slot), tok, key)
         self._pos[slot] = len(req.prompt)
         self._temp[slot] = req.temperature
         self._stats["admitted"] += 1
