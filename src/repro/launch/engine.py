@@ -359,8 +359,12 @@ def _decode_multi(params, pool, tok, pos, keys, temps, remaining,
     idle slots (always 0) and slots whose stop fires at micro-step j < K
     coexist with live rows in one program.  The pool tree and the
     token/key carries are donated: the engine immediately rebinds them to
-    the returned arrays, so XLA reuses the buffers across macro-steps
-    instead of copying the KV pool every dispatch."""
+    the returned arrays, so each output may take its input's buffer.
+    Donation alone does not avoid a copy of the pool: ``lm.decode_step``
+    writes each layer group's new state into the carried pool at the
+    group's index, so the pool is updated in place in the donated buffer
+    (a stacked scan output would be a fresh buffer copied whole into it
+    every dispatch)."""
     DECODE_TRACES[0] += 1
 
     def sample(logits, aux):

@@ -392,18 +392,29 @@ def decode_step(params: Dict[str, Any], state: Dict[str, Any], token: Array,
     else:
         x = token.astype(cfg.cdtype)
 
-    def scan_fn(x, gs):
-        group_params, group_state = gs
+    # The state rides the carry and each group's new state is written back
+    # at its index, so a donated pool is updated in its own buffer.  As
+    # scan xs/ys it went to a fresh buffer that was then copied whole.
+    def scan_fn(carry, gp):
+        x, state = carry
+        i, group_params = gp
+        group_state = jax.tree.map(
+            lambda l: jax.lax.dynamic_index_in_dim(l, i, 0, keepdims=False),
+            state)
         x, new_state = decode_group(group_params, group_state, x, pos, cfg,
                                     page_table=page_table)
-        return x, new_state
+        state = jax.tree.map(
+            lambda l, n: jax.lax.dynamic_update_index_in_dim(l, n, i, 0),
+            state, new_state)
+        return (x, state), None
 
-    x, new_states = jax.lax.scan(scan_fn, x, (params["groups"], state),
-                                 unroll=min(SCAN_UNROLL, cfg.n_groups))
+    (x, state), _ = jax.lax.scan(
+        scan_fn, (x, state), (jnp.arange(cfg.n_groups), params["groups"]),
+        unroll=min(SCAN_UNROLL, cfg.n_groups))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params.get("head", params["embed"].T if cfg.tie_embeddings else None)
     logits = unembed(x, head, cfg.logit_softcap)
-    return logits, new_states
+    return logits, state
 
 
 def decode_scan(params: Dict[str, Any], state: Dict[str, Any], tok: Array,
@@ -425,7 +436,9 @@ def decode_scan(params: Dict[str, Any], state: Dict[str, Any], tok: Array,
     deliberately NOT masked per row (that would copy the whole pool every
     micro-step): frozen attention rows rewrite their own cache rows
     idempotently and frozen recurrent rows advance into garbage a later
-    ``scatter`` overwrites wholesale.
+    ``scatter`` overwrites wholesale.  ``decode_step`` writes each layer
+    group's slice of the carried state in place, so a donated pool is
+    updated in its own buffer.
 
     ``page_table`` rides the scan as a loop-invariant operand: admission
     reserves every page a request will ever touch up front, so advancing

@@ -4,6 +4,7 @@ Every assigned architecture is instantiated at a REDUCED same-family config
 and run one forward/train step on CPU, asserting shapes and finiteness; the
 full configs are exercised only via the dry-run (ShapeDtypeStruct)."""
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
@@ -12,6 +13,9 @@ import pytest
 
 from repro.configs import ARCHS, get_config, get_smoke_config, input_specs, SHAPES
 from repro.models import lm
+from repro.models.blocks import decode_group
+from repro.models.common import embed_lookup, rms_norm, unembed
+from repro.models.kv_pool import SlotStatePool
 
 KEY = jax.random.PRNGKey(0)
 B, S = 2, 16
@@ -172,3 +176,80 @@ def test_input_specs_complete():
         for shape in SHAPES:
             spec = input_specs(cfg, shape)
             assert spec, (arch, shape)
+
+
+def _decode_step_stacked(params, state, token, pos, cfg, page_table=None):
+    """The layer scan as it was before the state rode the carry: the
+    pool scanned as xs and the new state stacked as ys.  The reference
+    for the in-place ``lm.decode_step``."""
+    x = embed_lookup(params["embed"], token, cfg.cdtype)
+    x = x * jnp.asarray(math.sqrt(cfg.d_model), cfg.cdtype)
+
+    def scan_fn(x, gs):
+        group_params, group_state = gs
+        return decode_group(group_params, group_state, x, pos, cfg,
+                            page_table=page_table)
+
+    x, new_states = jax.lax.scan(scan_fn, x, (params["groups"], state),
+                                 unroll=min(lm.SCAN_UNROLL, cfg.n_groups))
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    head = params.get("head", params["embed"].T if cfg.tie_embeddings else None)
+    return unembed(x, head, cfg.logit_softcap), new_states
+
+
+def _filled_pool(cfg, capacity, page_size):
+    """A slot pool with every slot's pages mapped and every state leaf
+    filled with noise, so a leaf the step failed to write shows."""
+    pool = SlotStatePool(cfg, capacity=capacity, max_len=24,
+                         page_size=page_size)
+    for slot in range(capacity):
+        pool.alloc(slot, 24)
+    leaves, treedef = jax.tree.flatten(pool.tree)
+    keys = jax.random.split(jax.random.PRNGKey(3), len(leaves))
+    leaves = [(jax.random.normal(k, l.shape) * 0.5).astype(l.dtype)
+              for k, l in zip(keys, leaves)]
+    return jax.tree.unflatten(treedef, leaves), pool.page_table
+
+
+def _greedy(logits, remaining):
+    live = remaining > 0
+    toks = jnp.argmax(logits, -1).astype(jnp.int32)
+    return toks, jnp.where(live, remaining - 1, remaining), live
+
+
+def _assert_trees_equal(a, b):
+    for (path, x), y in zip(jax.tree_util.tree_leaves_with_path(a),
+                            jax.tree.leaves(b)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
+                                      err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("arch,page_size", [
+    ("rwkv6-7b", 0), ("qwen2-72b", 8), ("jamba-1.5-large-398b", 0)],
+    ids=["rwkv6", "qwen2-paged", "jamba-mamba"])
+@pytest.mark.parametrize("k", [0, 1, 4], ids=["step", "scan1", "scan4"])
+def test_decode_in_place_bit_identical(monkeypatch, arch, page_size, k):
+    """Writing each group's new state into the carried pool gives the same
+    logits and every state leaf bit for bit as the stacked xs/ys scan, in
+    one step (k=0) and in fused decode scans of K=1 and K=4."""
+    cfg = get_smoke_config(arch)
+    params = lm.init_params(KEY, cfg)
+    capacity = 4
+    state, table = _filled_pool(cfg, capacity, page_size)
+    tok = jax.random.randint(KEY, (capacity, 1), 0, cfg.vocab)
+    pos = jnp.array([3, 9, 0, 15], jnp.int32)
+    remaining = jnp.array([4, 2, 0, 3], jnp.int32)
+
+    def run():
+        if k == 0:
+            return jax.jit(lambda p, s, t, q, pt: lm.decode_step(
+                p, s, t, q, cfg, page_table=pt))(params, state, tok, pos,
+                                                 table)
+        return jax.jit(lambda p, s, t, q, a, pt: lm.decode_scan(
+            p, s, t, q, cfg, a, _greedy, k, page_table=pt))(
+                params, state, tok, pos, remaining, table)
+
+    got = run()
+    monkeypatch.setattr(lm, "decode_step", _decode_step_stacked)
+    want = run()
+    _assert_trees_equal(got, want)

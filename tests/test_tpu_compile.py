@@ -1,12 +1,14 @@
-"""AOT compiles of the fused Pallas kernels for a described TPU v5e.
+"""AOT compiles for a described TPU v5e: the fused Pallas kernels, and
+where the compiled decode step places the state pool.
 
 Interpret mode (every other kernel test) cannot see what Mosaic refuses:
 block dims that are neither multiples of (8, 128) nor the full array dims,
 or more fast memory than a kernel may use.  These tests compile the fused
 kernels at the real widths of the serving path for a v5e chip that is
 described, not attached, and assert each lowers to a Mosaic kernel
-(``tpu_custom_call``).  Nothing runs, so they say nothing about results or
-times.
+(``tpu_custom_call``).  The CPU backend inserts copies of its own, so only
+a TPU module shows whether the decode step writes the donated pool in
+place.  Nothing runs, so they say nothing about results or times.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU compiler library, and with several test
@@ -14,6 +16,7 @@ workers the others must still collect the same tests.  Keep every such
 compile in this one file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -128,3 +131,47 @@ def test_resnet50_specs_match_inventory():
     assert specs["layer2.0.conv2"] == EpitomeSpec(
         M=1152, N=128, m=288, n=128, bm=256, bn=128)
     assert np.prod([8, 28, 28]) == 6272
+
+
+_HLO_DTYPE = {"float32": "f32", "bfloat16": "bf16"}
+
+
+def _hlo_shape(leaf):
+    return f"{_HLO_DTYPE[str(leaf.dtype)]}[{','.join(map(str, leaf.shape))}]"
+
+
+def test_decode_writes_state_pool_in_place(one_chip):
+    """The engine's decode dispatch updates the donated state pool in
+    place: no pool leaf is allocated afresh and the f32 recurrent state is
+    never copied whole.  Scanning the pool as xs/ys put one AllocateBuffer
+    per leaf in the module and a root copy of each into the donated
+    buffer.  (The smoke size's 2 KB bf16 ``x_prev`` leaves are staged
+    through fast memory by copies either way; at the served widths those
+    copies go too.)"""
+    from repro.configs import get_smoke_config
+    from repro.launch import engine
+    from repro.models import lm
+    from repro.models.kv_pool import SlotStatePool
+    C = 8
+    cfg = get_smoke_config("rwkv6-7b")
+
+    def sds(leaf):
+        return _sds(leaf.shape, leaf.dtype, one_chip)
+    params = jax.tree.map(sds, jax.eval_shape(
+        lambda key: lm.init_params(key, cfg), jax.random.PRNGKey(0)))
+    pool = jax.tree.map(sds, jax.eval_shape(
+        lambda: SlotStatePool(cfg, C, 24).tree))
+    rows = lambda *shape, dtype=jnp.int32: _sds((C,) + shape, dtype, one_chip)
+    text = engine._decode_multi.lower(
+        params, pool, rows(1), rows(), rows(2, dtype=jnp.uint32),
+        rows(dtype=jnp.float32), rows(), None, cfg=cfg, k=1
+    ).compile().as_text()
+
+    pool_shapes = {_hlo_shape(l) for l in jax.tree.leaves(pool)}
+    s = _hlo_shape(pool["L0"]["s"])
+    assert s == f"f32[{cfg.n_groups},{C},4,16,16]", s
+    allocs = re.findall(r"= (\w+\[[\d,]*\])\{[^}]*\} custom-call\(\)"
+                        r"[^\n]*AllocateBuffer", text)
+    assert not pool_shapes & set(allocs), allocs
+    copies = re.findall(r"= (\w+\[[\d,]*\])\{[^}]*\} copy\(", text)
+    assert s not in copies, copies
