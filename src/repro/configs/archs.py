@@ -1,10 +1,12 @@
-"""The ten assigned architectures, exact published configs.
+"""The assigned architectures, exact published configs, and Jamba2-Mini
+with its one-chip share.
 
 Sources per the assignment sheet: rwkv6 [arXiv:2404.05892], phi3.5-moe
 [hf:microsoft/Phi-3.5-MoE-instruct], grok-1 [hf:xai-org/grok-1], jamba-1.5
 [arXiv:2403.19887], qwen2-72b [arXiv:2407.10671], qwen1.5-110b [hf:Qwen],
 gemma2-2b [arXiv:2408.00118], deepseek-67b [arXiv:2401.02954], musicgen-large
-[arXiv:2306.05284], internvl2-76b [arXiv:2404.16821].
+[arXiv:2306.05284], internvl2-76b [arXiv:2404.16821], jamba2-mini
+[hf:ai21labs/AI21-Jamba2-Mini config.json].
 """
 from __future__ import annotations
 
@@ -58,6 +60,32 @@ def jamba_15_large(ep: EpitomeSettings) -> ModelConfig:
         n_experts=16, top_k=2,
         mamba_d_state=16, mamba_d_conv=4, mamba_expand=2,
         epitome=ep)
+
+
+def jamba2_mini(ep: EpitomeSettings) -> ModelConfig:
+    # AI21 Jamba2-Mini (config.json of ai21labs/AI21-Jamba2-Mini): attention
+    # at offset 4 of every 8 layers, MoE at offset 1 of every 2; no
+    # positional encoding; RMSNorms on dt, B, C; softmax over all 16
+    # router logits, top-2 not renormalised; dt_rank 256 is d_model / 16
+    return ModelConfig(
+        name="jamba2-mini", n_layers=32, d_model=4096, n_heads=32,
+        n_kv_heads=8, d_ff=14336, vocab=65536,
+        pattern=("mamba", "mamba", "mamba", "mamba",
+                 "attn", "mamba", "mamba", "mamba"),
+        ffn_pattern=("dense", "moe", "dense", "moe",
+                     "dense", "moe", "dense", "moe"),
+        n_experts=16, top_k=2, moe_renormalize=False,
+        mamba_d_state=16, mamba_d_conv=4, mamba_expand=2,
+        mamba_dtbc_norm=True, rope=False, norm_eps=1e-6,
+        epitome=ep)
+
+
+def jamba2_mini_ep2(ep: EpitomeSettings) -> ModelConfig:
+    # one chip of Jamba2-Mini over 8 chips: 4 pipeline stages of 8 layers,
+    # each stage an expert-parallel pair holding 8 of the 16 experts; this
+    # chip is stage 0's first, experts 0-7
+    return dataclasses.replace(jamba2_mini(ep), name="jamba2-mini-ep2",
+                               n_layers=8, experts_held=(0, 8))
 
 
 def qwen2_72b(ep: EpitomeSettings) -> ModelConfig:
@@ -122,6 +150,8 @@ BUILDERS = {
     "phi3.5-moe-42b-a6.6b": phi35_moe,
     "grok-1-314b": grok_1,
     "jamba-1.5-large-398b": jamba_15_large,
+    "jamba2-mini": jamba2_mini,
+    "jamba2-mini-ep2": jamba2_mini_ep2,
     "qwen2-72b": qwen2_72b,
     "qwen1.5-110b": qwen15_110b,
     "gemma2-2b": gemma2_2b,

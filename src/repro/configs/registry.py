@@ -167,6 +167,9 @@ def get_smoke_config(arch: str, epitome: str = "off",
     layer_config = ()
     if plan is not None:
         layer_config = _plan_layer_config(plan, f"{arch}-smoke")
+    n_experts = min(full.n_experts, 4)
+    # a held share keeps its fraction of the experts
+    held = tuple(e * n_experts // full.n_experts for e in full.experts_held)
     return dataclasses.replace(
         full,
         layer_config=layer_config,
@@ -177,7 +180,7 @@ def get_smoke_config(arch: str, epitome: str = "off",
         head_dim=16 if full.head_dim else 0,
         d_ff=96,
         vocab=192,
-        n_experts=min(full.n_experts, 4) if full.n_experts else 0,
+        n_experts=n_experts, experts_held=held,
         window=8,
         rwkv_lora_decay=8, rwkv_lora_mix=4,
         mamba_d_state=4, mamba_d_conv=4, mamba_expand=2,

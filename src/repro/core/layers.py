@@ -257,12 +257,13 @@ def prepack_tree(params, layer_configs: Mapping[str, EpLayerConfig],
     Walks a param pytree (e.g. the LM's ``params["groups"]``) and, for
     every linear-layer subdict whose '/'-joined path names a kernel x quant
     epitome entry of ``layer_configs``, packs the int8 codes once.  The
-    scanned LM stacks every leaf with a leading group axis, so the pack
-    runs under ``jax.vmap`` over that axis (``stacked=True``); the new
-    Eq/Es/Ez leaves then carry the same leading axis and slice per group
-    inside ``lax.scan`` exactly like E does.  Everything else — dense
-    layers, norms, paths the mapping does not name — passes through
-    untouched.
+    scanned LM stacks every leaf with a leading group axis, and an MoE
+    expert site its held experts on the next, so the pack runs under one
+    ``jax.vmap`` per leading axis of E (``stacked=True``); the new
+    Eq/Es/Ez leaves then carry the same leading axes and slice per group
+    (and per expert) inside ``lax.scan`` exactly like E does.  Everything
+    else — dense layers, norms, paths the mapping does not name — passes
+    through untouched.
 
     With ``mesh``, every layer subdict named by ``layer_configs`` is
     additionally laid out with a NamedSharding from its placement record
@@ -280,7 +281,11 @@ def prepack_tree(params, layer_configs: Mapping[str, EpLayerConfig],
             if (cfg.is_epitome and cfg.quant is not None
                     and cfg.mode == "kernel"):
                 pack = lambda p: prepack_linear(p, cfg)
-                out = jax.vmap(pack)(tree) if stacked else pack(tree)
+                # one vmap per stacked axis: the group axis, and an MoE
+                # site's expert axis after it
+                for _ in range(tree["E"].ndim - 2 if stacked else 0):
+                    pack = jax.vmap(pack)
+                out = pack(tree)
             if mesh is not None:
                 out = _place_layer(out, cfg, mesh)
             return out

@@ -50,9 +50,11 @@ API reference
     idle and returns every completion in submission order.  ``stats``
     counts ``prefill_traces`` / ``prefill_chunks`` / ``slot_reuses`` /
     ``decode_steps`` / ``decode_micro_steps`` / ``decode_traces`` /
-    ``completed`` / ``admitted``, and ``prefill_s``: host seconds from
+    ``completed`` / ``admitted``, ``prefill_s``: host seconds from
     each prefill call until its first token is on the host (see
-    Tracing); plus occupancy: ``queue_depth`` and the pool's
+    Tracing), and ``expert_rows``: the (token, held expert) pairs the MoE
+    layers routed in decode dispatches (counted on the device, read with
+    the tokens); plus occupancy: ``queue_depth`` and the pool's
     ``pages_total`` / ``pages_used`` / ``pages_free`` / ``pages_hwm`` /
     ``page_reuses``.
 
@@ -93,10 +95,11 @@ float32 so later chunks attend earlier chunks' K/V at exactly the
 precision the one-shot path attends them fresh (the final scatter into
 the pool rounds to cache dtype, exactly where the one-shot path
 rounds).  Short prompts (P <= chunk) keep the immediate bucketed
-one-shot prefill.  Exceptions that prefill whole-prompt: MoE arches
-(capacity routing couples every token in the dispatch) and int8 KV
-caches (chunk 2 would attend dequantized rows where one-shot attends
-fresh float K/V).
+one-shot prefill.  Exceptions that prefill whole-prompt: MoE layers
+that a mesh would run as a capacity dispatch (it couples every token in
+the dispatch; the per-token MoE path of one device is row-local and
+chunks like any layer) and int8 KV caches (chunk 2 would attend
+dequantized rows where one-shot attends fresh float K/V).
 
 Prompt bucketing
 ----------------
@@ -105,9 +108,9 @@ capped at the pool sequence length) so distinct prompt lengths reuse
 one compiled program per bucket; chunked prefills compile ONE program
 per (cfg, chunk) regardless of prompt length.  Pads sit strictly AFTER
 the real tokens and every mixer masks them to exact zeros / exact
-identities (``valid_len`` threading in models/*).  MoE architectures
-prefill at exact length (one trace per distinct length, documented
-trade-off).
+identities (``valid_len`` threading in models/*).  MoE layers that a
+mesh would run as a capacity dispatch prefill at exact length (one trace
+per distinct length).
 
 Bit-exactness contract
 ----------------------
@@ -156,7 +159,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs import get_config, get_smoke_config
-from ..models import lm
+from ..models import lm, moe
 from ..models.common import set_mesh
 from ..models.kv_pool import SlotStatePool, paged_leaf_paths
 from ..models.ssm import recurrence_alignment
@@ -364,7 +367,12 @@ def _decode_multi(params, pool, tok, pos, keys, temps, remaining,
     writes each layer group's new state into the carried pool at the
     group's index, so the pool is updated in place in the donated buffer
     (a stacked scan output would be a fresh buffer copied whole into it
-    every dispatch)."""
+    every dispatch).
+
+    The first returned block is (k + 1, C) int32: k rows of sampled
+    tokens, then each slot's (token, held expert) pairs routed by the MoE
+    layers over the k micro-steps, so the count comes back in the copy of
+    the tokens the retire makes anyway."""
     DECODE_TRACES[0] += 1
 
     def sample(logits, aux):
@@ -375,9 +383,10 @@ def _decode_multi(params, pool, tok, pos, keys, temps, remaining,
         remaining = jnp.where(live, remaining - 1, remaining)
         return toks, (keys, temps, remaining), live
 
-    pool, tok, _, (keys, _, _), toks, live = lm.decode_scan(
+    pool, tok, _, (keys, _, _), toks, live, routed = lm.decode_scan(
         params, pool, tok, pos, cfg, (keys, temps, remaining), sample, k,
         page_table=page_table)
+    toks = jnp.concatenate([toks, routed.sum(0, keepdims=True)], 0)
     return toks, live, pool, tok, keys
 
 
@@ -469,16 +478,18 @@ class EpimEngine:
             raise ValueError("decode_block must be >= 1")
         self.cfg, self.serve_params = cfg, serve_params
         self.capacity, self.max_len = capacity, max_len
-        # MoE capacity routing couples every batch row (pad tokens would
-        # consume expert-queue ranks), so MoE prompts prefill exact-length
-        self.bucket_prompts = "moe" not in cfg.ffn_pattern
+        # a capacity dispatch couples every row of a batch-1 prefill (pad
+        # tokens would take expert-queue ranks), so MoE prompts it would
+        # serve prefill exact-length; the per-token path is row-local
+        self.bucket_prompts = not moe.takes_dispatch(cfg, 1, max_len)
         self._pool = SlotStatePool(cfg, capacity, max_len,
                                    page_size=page_size, kv_pages=kv_pages)
         self.seq_len = self._pool.seq_len   # static prefill/decode KV rows
         # chunked prefill: aligned to the recurrence windows so chunk
         # boundaries are one-shot window boundaries (bit-exactness); off
-        # for MoE (token coupling) and int8 caches (chunk 2 would attend
-        # dequantized rows the one-shot path attends fresh)
+        # under a capacity dispatch (token coupling) and for int8 caches
+        # (chunk 2 would attend dequantized rows the one-shot path attends
+        # fresh)
         if prefill_chunk > 0 and self.bucket_prompts \
                 and cfg.kv_cache_bits != 8:
             align = recurrence_alignment(cfg)
@@ -506,7 +517,7 @@ class EpimEngine:
                        "decode_micro_steps": 0, "decode_traces": 0,
                        "completed": 0, "admitted": 0,
                        "prefill_traces": 0, "prefill_chunks": 0,
-                       "prefill_s": 0.0}
+                       "prefill_s": 0.0, "expert_rows": 0}
         # set by EngineConfig.build (None for a bare-constructed engine)
         self.config: Optional[EngineConfig] = None
         self.mesh = None
@@ -647,8 +658,9 @@ class EpimEngine:
             return 0
         with jax.profiler.TraceAnnotation("epim.retire"):
             with jax.profiler.TraceAnnotation("epim.retire.wait"):
-                toks = np.asarray(jax.device_get(inf.toks))   # (k, C)
+                toks = np.asarray(jax.device_get(inf.toks))   # (k + 1, C)
             now = time.perf_counter()
+            self._stats["expert_rows"] += int(toks[inf.k].sum())
             emitted = 0
             for slot, rec, n in inf.snapshot:
                 for j in range(n):

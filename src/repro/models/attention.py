@@ -1,4 +1,4 @@
-"""Attention: GQA, RoPE, flash-style chunked softmax, sliding windows,
+"""Attention: GQA, RoPE (or none), flash-style chunked softmax, sliding windows,
 softcapping (gemma2), training + prefill + decode paths.
 
 The training/prefill path is a memory-efficient chunked attention (online
@@ -44,6 +44,12 @@ def init_attn(key: Array, cfg: ModelConfig, prefix: str = "") -> dict:
         "wv": init_linear(kv, d, nkv * hd, cfg.ep(d, nkv * hd, _nm(prefix, "wv")), bias=cfg.qkv_bias, dtype=dt),
         "wo": init_linear(ko, nq * hd, d, cfg.ep(nq * hd, d, _nm(prefix, "wo")), dtype=dt),
     }
+
+
+def _rope(t: Array, positions: Array, cfg: ModelConfig) -> Array:
+    """RoPE, or ``t`` as it is for a model without positional encoding
+    (``cfg.rope`` False: jamba)."""
+    return apply_rope(t, positions, cfg.rope_theta) if cfg.rope else t
 
 
 # ---------------------------------------------------------------------------
@@ -128,8 +134,8 @@ def attention(params: dict, x: Array, cfg: ModelConfig, *,
     q = apply_linear(params["wq"], x, cfg.ep(d, nq * hd, _nm(prefix, "wq"))).reshape(B, S, nq, hd)
     k = apply_linear(params["wk"], x, cfg.ep(d, nkv * hd, _nm(prefix, "wk"))).reshape(B, S, nkv, hd)
     v = apply_linear(params["wv"], x, cfg.ep(d, nkv * hd, _nm(prefix, "wv"))).reshape(B, S, nkv, hd)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q = _rope(q, positions, cfg)
+    k = _rope(k, positions, cfg)
     # heads tensor-parallel
     q = shard(q, BATCH_AXES, None, TENSOR_AXIS, None)
     k = shard(k, BATCH_AXES, None, TENSOR_AXIS, None)
@@ -226,8 +232,8 @@ def chunked_prefill_attention(params: dict, x: Array, cache: dict,
     q = apply_linear(params["wq"], x, cfg.ep(d, nq * hd, _nm(prefix, "wq"))).reshape(B, C, nq, hd)
     k = apply_linear(params["wk"], x, cfg.ep(d, nkv * hd, _nm(prefix, "wk"))).reshape(B, C, nkv, hd)
     v = apply_linear(params["wv"], x, cfg.ep(d, nkv * hd, _nm(prefix, "wv"))).reshape(B, C, nkv, hd)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q = _rope(q, positions, cfg)
+    k = _rope(k, positions, cfg)
     q = shard(q, BATCH_AXES, None, TENSOR_AXIS, None)
     cache = dict(cache)
     cache["k"] = jax.lax.dynamic_update_slice_in_dim(
@@ -285,8 +291,8 @@ def decode_attention(params: dict, x: Array, cache: dict,
     v = apply_linear(params["wv"], x, cfg.ep(d, nkv * hd, _nm(prefix, "wv"))).reshape(B, 1, nkv, hd)
     posv = (pos[:, None] if per_row else
             jnp.full((1,), pos, jnp.int32) if jnp.ndim(pos) == 0 else pos[None])
-    q = apply_rope(q, posv, cfg.rope_theta)
-    k = apply_rope(k, posv, cfg.rope_theta)
+    q = _rope(q, posv, cfg)
+    k = _rope(k, posv, cfg)
     cache = dict(cache)
     if paged:
         # physical row of each slot's current token, then one flat scatter

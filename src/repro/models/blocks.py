@@ -15,7 +15,7 @@ from .attention import (
 )
 from .common import act_fn, init_rms_norm, rms_norm, shard, BATCH_AXES, TENSOR_AXIS
 from .config import LayerKind, ModelConfig, layer_name as _nm
-from .moe import init_moe, moe_ffn
+from .moe import init_moe, moe_ffn, moe_held
 from .ssm import (
     init_mamba, init_rwkv, init_rwkv_ffn,
     mamba_mix, rwkv_channel_mix, rwkv_time_mix,
@@ -77,7 +77,7 @@ def init_group(key: Array, cfg: ModelConfig) -> Dict[str, Any]:
         if ffn_kind == "dense":
             layer["ffn"] = init_ffn(k_ffn, cfg, prefix=ffn_p)
         elif ffn_kind == "moe":
-            layer["ffn"] = init_moe(k_ffn, cfg)
+            layer["ffn"] = init_moe(k_ffn, cfg, prefix=ffn_p)
         elif ffn_kind == "rwkv_ffn":
             layer["ffn"] = init_rwkv_ffn(k_ffn, cfg, prefix=ffn_p)
         params[f"L{i}"] = layer
@@ -110,7 +110,7 @@ def apply_group(params: Dict[str, Any], x: Array, cfg: ModelConfig,
         if ffn_kind == "dense":
             f = ffn(layer["ffn"], h, cfg, prefix=ffn_p)
         elif ffn_kind == "moe":
-            f = moe_ffn(layer["ffn"], h, cfg)
+            f = moe_ffn(layer["ffn"], h, cfg, prefix=ffn_p)
         elif ffn_kind == "rwkv_ffn":
             f, _ = rwkv_channel_mix(layer["ffn"], h, cfg, prefix=ffn_p)
         x = x + f
@@ -194,7 +194,7 @@ def prefill_group(params: Dict[str, Any], state: Dict[str, Any], x: Array,
             if ffn_kind == "dense":
                 f = ffn(layer["ffn"], h, cfg, prefix=ffn_p)
             elif ffn_kind == "moe":
-                f = moe_ffn(layer["ffn"], h, cfg)
+                f = moe_ffn(layer["ffn"], h, cfg, prefix=ffn_p)
             elif ffn_kind == "rwkv_ffn":
                 f, xp2 = rwkv_channel_mix(layer["ffn"], h, cfg,
                                           x_prev=st.get("ffn_x_prev", jnp.zeros(
@@ -232,14 +232,18 @@ def init_group_state(cfg: ModelConfig, batch: int, max_len: int) -> Dict[str, An
 def decode_group(params: Dict[str, Any], state: Dict[str, Any], x: Array,
                  pos: Array, cfg: ModelConfig,
                  page_table: Optional[Array] = None
-                 ) -> Tuple[Array, Dict[str, Any]]:
-    """x: (B, 1, d).  Returns (x, new_state).
+                 ) -> Tuple[Array, Dict[str, Any], Array]:
+    """x: (B, 1, d).  Returns (x, new_state, routed): ``routed`` (B,) int32
+    counts, per row, the (token, held expert) pairs the group's MoE layers
+    routed (0 without MoE).  Decode runs the per-token MoE path: a decode
+    batch of one token per row never fills capacity buffers.
 
     ``page_table`` (B, pages_per_slot): the attention layers' k/v state
     leaves are a shared block-paged pool (models/kv_pool.py) rather than
     per-row dense caches; decode_attention reads and writes through the
     table.  SSM leaves are dense per-row either way."""
     new_state: Dict[str, Any] = {}
+    routed = jnp.zeros((x.shape[0],), jnp.int32)
     for i, (kind, ffn_kind) in enumerate(cfg.full_pattern):
         layer = params[f"L{i}"]
         st = state[f"L{i}"]
@@ -268,7 +272,8 @@ def decode_group(params: Dict[str, Any], state: Dict[str, Any], x: Array,
             if ffn_kind == "dense":
                 f = ffn(layer["ffn"], h, cfg, prefix=ffn_p)
             elif ffn_kind == "moe":
-                f = moe_ffn(layer["ffn"], h, cfg)
+                f, r = moe_held(layer["ffn"], h, cfg, prefix=ffn_p)
+                routed = routed + r[:, 0]
             elif ffn_kind == "rwkv_ffn":
                 f, xp2 = rwkv_channel_mix(layer["ffn"], h, cfg,
                                           x_prev=st["ffn_x_prev"].astype(h.dtype),
@@ -276,4 +281,4 @@ def decode_group(params: Dict[str, Any], state: Dict[str, Any], x: Array,
                 ns["ffn_x_prev"] = xp2.astype(cfg.cdtype)
             x = x + f
         new_state[f"L{i}"] = ns
-    return x, new_state
+    return x, new_state, routed

@@ -116,6 +116,7 @@ class ModelConfig:
     qkv_bias: bool = False                 # qwen
     window: int = 4096                     # sliding window for ATTN_LOCAL
     rope_theta: float = 10000.0
+    rope: bool = True                      # False: no positional encoding
     attn_softcap: float = 0.0              # gemma2: 50.0
     logit_softcap: float = 0.0             # gemma2: 30.0
 
@@ -124,11 +125,19 @@ class ModelConfig:
     top_k: int = 2
     capacity_factor: float = 1.25
     router_jitter: float = 0.0
+    # experts [lo, hi) held by this device (expert parallelism: the layer
+    # routes over all n_experts and computes its own experts' part); ()
+    # holds them all
+    experts_held: Tuple[int, ...] = ()
+    # top-k weights renormalised over the k chosen (softmax of the top-k
+    # logits); False keeps the softmax over all n_experts (jamba)
+    moe_renormalize: bool = True
 
     # Mamba (jamba)
     mamba_d_state: int = 16
     mamba_d_conv: int = 4
     mamba_expand: int = 2
+    mamba_dtbc_norm: bool = False          # RMSNorm dt, B, C after x_proj
 
     # RWKV6
     rwkv_lora_decay: int = 64
@@ -144,7 +153,6 @@ class ModelConfig:
     seq_shard_residual: bool = True    # Megatron-SP residual (False = pure TP)
     remat_policy: str = "nothing"      # nothing | dots (save matmul outputs)
     kv_cache_bits: int = 16            # 8 = int8 KV cache w/ per-tile scales
-    moe_decode_dispatch: bool = False  # all_to_all dispatch even at decode
 
     # misc
     act: str = "silu"                      # silu | gelu
@@ -184,6 +192,11 @@ class ModelConfig:
                              f"multiple of pattern {len(self.pattern)}")
         if len(self.ffn_pattern) not in (1, len(self.pattern)):
             raise ValueError(f"{self.name}: ffn_pattern length mismatch")
+        if self.experts_held:
+            lo, hi = self.experts_held
+            if not 0 <= lo < hi <= self.n_experts:
+                raise ValueError(f"{self.name}: experts_held {lo, hi} not "
+                                 f"inside [0, {self.n_experts})")
 
     # -- derived -------------------------------------------------------------
     @property
@@ -203,6 +216,15 @@ class ModelConfig:
     @property
     def mamba_d_inner(self) -> int:
         return self.mamba_expand * self.d_model
+
+    @property
+    def dt_rank(self) -> int:
+        return max(1, self.d_model // 16)
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """[lo, hi) of the experts this device holds."""
+        return self.experts_held or (0, self.n_experts)
 
     @property
     def pdtype(self):
@@ -243,12 +265,13 @@ class ModelConfig:
             elif kind == LayerKind.MAMBA.value:
                 di, ds = self.mamba_d_inner, self.mamba_d_state
                 n += reps * (d * 2 * di + di * self.mamba_d_conv
-                             + di * (ds * 2 + di // 16 + ds) + di * d)
+                             + di * (ds * 2 + 2 * self.dt_rank + ds) + di * d)
             elif kind == LayerKind.RWKV.value:
                 n += reps * (4 * d * d + d * self.rwkv_lora_decay * 2
                              + 5 * d * self.rwkv_lora_mix * 2)
             if ffn == "moe":
-                e = self.n_experts if not active_only else self.top_k
+                lo, hi = self.held_experts
+                e = hi - lo if not active_only else self.top_k
                 n += reps * (e * 3 * d * ff + d * self.n_experts)
             elif ffn == "dense":
                 mult = 3 if self.act in ("silu", "gelu") else 2

@@ -140,15 +140,15 @@ def _leaf_spec(path: str, shape: Tuple[int, ...]) -> P:
 
     # fan-out projections: output dim on 'model', input dim FSDP on 'data'
     fan_out = ("wq/W", "wk/W", "wv/W", "wg/W", "wr/W", "in_proj/W",
-               "x_proj/W", "dt_proj/W", "w_gate/W", "w_up/W", "w_gate",
-               "w_up", "wq/E", "wk/E", "wv/E", "wg/E", "wr/E", "in_proj/E",
-               "x_proj/E", "dt_proj/E", "w_gate/E", "w_up/E")
+               "x_proj/W", "dt_proj/W", "w_gate/W", "w_up/W", "wq/E",
+               "wk/E", "wv/E", "wg/E", "wr/E", "in_proj/E", "x_proj/E",
+               "dt_proj/E", "w_gate/E", "w_up/E")
     # fan-in projections: input dim on 'model' (it carries d_ff / heads)
-    fan_in = ("wo/W", "out_proj/W", "w_down/W", "w_down", "wo/E",
-              "out_proj/E", "w_down/E")
+    fan_in = ("wo/W", "out_proj/W", "w_down/W", "wo/E", "out_proj/E",
+              "w_down/E")
 
     if last(*fan_out):
-        if len(shape) == 4:        # stacked MoE experts (G, E, d, ff)
+        if len(shape) == 4:        # MoE expert sites (G, E_held, d, ff)
             return P(None, None, "data", TENSOR_AXIS)
         return P(None, "data", TENSOR_AXIS)
     if last(*fan_in):
@@ -302,8 +302,8 @@ def state_specs(cfg: ModelConfig, state_shape: Dict[str, Any],
             return P(None, None, ("pod", "data", "model"), None, None)
         if path.endswith("/s"):            # rwkv state (G,B,H,K,V)
             return P(None, BATCH_AXES if batch > 1 else None, TENSOR_AXIS, None, None)
-        if path.endswith("/h"):            # mamba state (G,B,di,ds)
-            return P(None, BATCH_AXES if batch > 1 else None, TENSOR_AXIS, None)
+        if path.endswith("/h"):            # mamba state (G,B,ds,di)
+            return P(None, BATCH_AXES if batch > 1 else None, None, TENSOR_AXIS)
         if path.endswith("/conv"):         # (G,B,dc-1,di)
             return P(None, BATCH_AXES if batch > 1 else None, None, TENSOR_AXIS)
         return P(*([None] * len(shp)))
@@ -377,15 +377,16 @@ def prefill(params: Dict[str, Any], inputs: Array, state: Dict[str, Any],
 
 def decode_step(params: Dict[str, Any], state: Dict[str, Any], token: Array,
                 pos: Array, cfg: ModelConfig,
-                page_table: Optional[Array] = None
-                ) -> Tuple[Array, Dict[str, Any]]:
+                page_table: Optional[Array] = None, routed: bool = False):
     """token: (B, 1) int32 (or (B, 1, d) embeddings); pos: scalar int32,
     or (B,) int32 per-row positions (continuous batching: every slot of
     the engine's state pool sits at its own sequence position).
     ``page_table`` (B, pages_per_slot, requires per-row pos): the state's
     attention k/v leaves are a shared block-paged pool read/written
     through the table (models/kv_pool.py) instead of dense per-row rows.
-    Returns (logits (B, 1, vocab), new state)."""
+    Returns (logits (B, 1, vocab), new state), and with ``routed`` also
+    the (B,) int32 count of (token, held expert) pairs the MoE layers
+    routed per row."""
     if token.ndim == 2:
         x = embed_lookup(params["embed"], token, cfg.cdtype)
         x = x * jnp.asarray(math.sqrt(cfg.d_model), cfg.cdtype)
@@ -396,25 +397,26 @@ def decode_step(params: Dict[str, Any], state: Dict[str, Any], token: Array,
     # at its index, so a donated pool is updated in its own buffer.  As
     # scan xs/ys it went to a fresh buffer that was then copied whole.
     def scan_fn(carry, gp):
-        x, state = carry
+        x, state, n = carry
         i, group_params = gp
         group_state = jax.tree.map(
             lambda l: jax.lax.dynamic_index_in_dim(l, i, 0, keepdims=False),
             state)
-        x, new_state = decode_group(group_params, group_state, x, pos, cfg,
-                                    page_table=page_table)
+        x, new_state, r = decode_group(group_params, group_state, x, pos,
+                                       cfg, page_table=page_table)
         state = jax.tree.map(
             lambda l, n: jax.lax.dynamic_update_index_in_dim(l, n, i, 0),
             state, new_state)
-        return (x, state), None
+        return (x, state, n + r), None
 
-    (x, state), _ = jax.lax.scan(
-        scan_fn, (x, state), (jnp.arange(cfg.n_groups), params["groups"]),
+    n0 = jnp.zeros((x.shape[0],), jnp.int32)
+    (x, state, n), _ = jax.lax.scan(
+        scan_fn, (x, state, n0), (jnp.arange(cfg.n_groups), params["groups"]),
         unroll=min(SCAN_UNROLL, cfg.n_groups))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params.get("head", params["embed"].T if cfg.tie_embeddings else None)
     logits = unembed(x, head, cfg.logit_softcap)
-    return logits, state
+    return (logits, state, n) if routed else (logits, state)
 
 
 def decode_scan(params: Dict[str, Any], state: Dict[str, Any], tok: Array,
@@ -445,17 +447,20 @@ def decode_scan(params: Dict[str, Any], state: Dict[str, Any], tok: Array,
     ``pos`` inside the carry walks the table across page boundaries
     without the host re-mapping anything mid-scan.
 
-    Returns ``(state, tok, pos, aux, toks, live)`` with ``toks``/``live``
-    stacked (k, B) — the per-micro-step emissions and their validity."""
+    Returns ``(state, tok, pos, aux, toks, live, routed)`` with
+    ``toks``/``live``/``routed`` stacked (k, B) — the per-micro-step
+    emissions, their validity, and the (token, held expert) pairs each
+    live row's MoE layers routed (``decode_step``'s count, 0 on frozen
+    rows)."""
     def body(carry, _):
         state, tok, pos, aux = carry
-        logits, state = decode_step(params, state, tok, pos, cfg,
-                                    page_table=page_table)
+        logits, state, n = decode_step(params, state, tok, pos, cfg,
+                                       page_table=page_table, routed=True)
         toks, aux, live = sample(logits[:, -1], aux)
         tok = jnp.where(live[:, None], toks[:, None].astype(tok.dtype), tok)
         pos = jnp.where(live, pos + 1, pos)
-        return (state, tok, pos, aux), (toks, live)
+        return (state, tok, pos, aux), (toks, live, jnp.where(live, n, 0))
 
-    (state, tok, pos, aux), (toks, live) = jax.lax.scan(
+    (state, tok, pos, aux), (toks, live, routed) = jax.lax.scan(
         body, (state, tok, pos, aux), None, length=k)
-    return state, tok, pos, aux, toks, live
+    return state, tok, pos, aux, toks, live, routed

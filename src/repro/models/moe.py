@@ -1,98 +1,149 @@
-"""Mixture-of-Experts with real expert parallelism.
+"""Mixture-of-Experts: a router over all experts and the experts a device
+holds, each expert's projections an ``init_linear``/``apply_linear`` site
+stacked over the held experts (so epitomes, prepack and the fused int8
+kernel reach them).
 
-Three execution paths (DESIGN.md §5):
+Two execution paths:
 
-1. ``dispatch``    — training / prefill at scale: `shard_map` over the batch
-   axes; tokens are routed locally (sort-free rank-within-expert via cumsum
-   counts), packed into per-destination-shard capacity buffers, exchanged
-   with `lax.all_to_all`, run through the local expert (whose d_ff is still
-   tensor-parallel: the matmuls are manually psum'd over 'model'), and
-   returned.  Expert weights live fully sharded (E replicated, d_model over
-   data, d_ff over model) and are reshaped to per-shard slots with a
-   sharding constraint — XLA turns that into the FSDP-style expert
-   all-gather, and its transpose into the gradient reduce-scatter.
-2. ``dense``       — decode / tiny token counts: every expert computes every
-   token, masked combine; weights stay resident.  FLOPs = E/topk times the
-   dispatch path, but decode is bandwidth-bound and this is exactly how
-   small-batch MoE serving reads weights anyway.
-3. plain fallback  — no mesh installed (CPU smoke tests): same math as
-   ``dense``.
-
-Capacity model: per-destination-shard capacity C = ceil(T_local * topk * cf
-/ n_shards); overflow tokens are dropped (standard GShard behaviour), tests
-use cf large enough for zero drops when checking dispatch == dense.
+1. per token (``moe_held``): every held expert computes every row and is
+   combined with the row's routing weight (0 where the row did not pick
+   it).  Dropless, with no capacity, so rows never couple: bucketing pads
+   and prefill chunks change no real row.  It is the one-device path, and
+   the path of a device that holds a share of the experts (expert
+   parallelism, model-configs guide §4): it routes over all
+   ``n_experts`` and returns its own experts' part of the output.
+2. capacity dispatch (``moe_dispatch``) — training / prefill at scale on a
+   mesh whose data shards map onto the experts: ``shard_map`` over the
+   batch axes; tokens are routed locally (sort-free rank-within-expert via
+   cumsum counts), packed into per-destination-shard capacity buffers,
+   exchanged with ``lax.all_to_all``, run through the local expert, and
+   returned.  Capacity C = ceil(T_local * topk * cf / n_shards); overflow
+   tokens are dropped (GShard), which couples the tokens of a dispatch.
+   ``takes_dispatch`` says when ``moe_ffn`` runs it.
 """
 from __future__ import annotations
 
 import math
-from functools import partial
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..core.layers import apply_linear, init_linear
-from .common import act_fn, get_mesh, shard, BATCH_AXES, TENSOR_AXIS
-from .config import ModelConfig
+from ..core.layers import apply_linear, effective_weight
+from .common import act_fn, get_mesh, BATCH_AXES, TENSOR_AXIS
+from .config import ModelConfig, layer_name as _nm
 
 Array = jax.Array
 
 
-def init_moe(key: Array, cfg: ModelConfig) -> dict:
-    d, ff, E = cfg.d_model, cfg.d_ff, cfg.n_experts
-    kr, kg, ku, kd = jax.random.split(key, 4)
-    dt = cfg.pdtype
-    scale_in = 1.0 / math.sqrt(d)
-    scale_out = 1.0 / math.sqrt(ff)
-    return {
-        "router": (jax.random.normal(kr, (d, E)) * scale_in).astype(jnp.float32),
-        "w_gate": (jax.random.normal(kg, (E, d, ff)) * scale_in).astype(dt),
-        "w_up": (jax.random.normal(ku, (E, d, ff)) * scale_in).astype(dt),
-        "w_down": (jax.random.normal(kd, (E, ff, d)) * scale_out).astype(dt),
-    }
+_EXPERT_SITES = (("w_gate", False), ("w_up", False), ("w_down", True))
 
 
-def moe_param_specs(cfg: ModelConfig) -> dict:
-    """E replicated; d_model FSDP-sharded over data; d_ff TP over model."""
-    return {
-        "router": P(None, None),
-        "w_gate": P(None, "data", TENSOR_AXIS),
-        "w_up": P(None, "data", TENSOR_AXIS),
-        "w_down": P(None, TENSOR_AXIS, "data"),
-    }
+def _site_ep(cfg: ModelConfig, name: str, down: bool, prefix: str):
+    d, ff = cfg.d_model, cfg.d_ff
+    M, N = (ff, d) if down else (d, ff)
+    return M, N, cfg.ep(M, N, _nm(prefix, name))
+
+
+def init_moe(key: Array, cfg: ModelConfig, prefix: str = "") -> dict:
+    """Router over all ``n_experts`` and the held experts' projections,
+    each a linear site stacked over the held experts: ``{"W": (E_held, M,
+    N)}`` dense, or ``{"E": (E_held, m, n)}`` an epitome per expert.  All
+    experts are drawn in one call and the held ones kept, so a share's
+    experts are the same whichever device holds them."""
+    E = cfg.n_experts
+    lo, hi = cfg.held_experts
+    kr, *ks = jax.random.split(key, 4)
+    params = {"router": (jax.random.normal(kr, (cfg.d_model, E))
+                         * (1.0 / math.sqrt(cfg.d_model))).astype(jnp.float32)}
+    for k, (name, down) in zip(ks, _EXPERT_SITES):
+        M, N, lc = _site_ep(cfg, name, down, prefix)
+        shape = (lc.spec.m, lc.spec.n) if lc.is_epitome else (M, N)
+        w = (jax.random.normal(k, (E,) + shape)
+             * (1.0 / math.sqrt(M))).astype(cfg.pdtype)
+        params[name] = {"E" if lc.is_epitome else "W": w[lo:hi]}
+    return params
+
+
+def route(x2d: Array, router: Array, cfg: ModelConfig) -> Array:
+    """(T, n_experts) float32 combine weights: each token's top-k experts
+    carry their weight, the rest 0.  ``moe_renormalize``: softmax over the
+    top-k logits; else the softmax over all experts, top-k kept as they
+    are (jamba)."""
+    logits = x2d.astype(jnp.float32) @ router
+    if cfg.moe_renormalize:
+        weights, experts = jax.lax.top_k(logits, cfg.top_k)
+        weights = jax.nn.softmax(weights, axis=-1)
+    else:
+        weights, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                         cfg.top_k)
+    T = x2d.shape[0]
+    return jnp.zeros((T, cfg.n_experts), jnp.float32).at[
+        jnp.arange(T)[:, None], experts].add(weights)
 
 
 def _route(x2d: Array, router: Array, cfg: ModelConfig
            ) -> Tuple[Array, Array]:
-    """top-k routing.  x2d: (T, d) -> (weights (T,k), experts (T,k))."""
-    logits = x2d.astype(jnp.float32) @ router
-    weights, experts = jax.lax.top_k(logits, cfg.top_k)
-    weights = jax.nn.softmax(weights, axis=-1)
+    """top-k routing for the dispatch path: (weights (T,k), experts
+    (T,k)), with ``route``'s weights."""
+    comb = route(x2d, router, cfg)
+    weights, experts = jax.lax.top_k(comb, cfg.top_k)
     return weights, experts
 
 
+def _stacked_weight(p: dict, lc) -> Array:
+    """(E_held, M, N) dense weights of a stacked expert site."""
+    if "W" in p:
+        return p["W"]
+    return jax.vmap(lambda e: effective_weight({"E": e}, lc))(p["E"])
+
+
 # ---------------------------------------------------------------------------
-# Path 2/3: dense-masked (decode, smoke tests, reference)
+# Per-token path: one device, every held expert on every row
 # ---------------------------------------------------------------------------
-def moe_dense(params: dict, x: Array, cfg: ModelConfig) -> Array:
+@jax.named_scope("epim.moe")
+def moe_held(params: dict, x: Array, cfg: ModelConfig, prefix: str = ""
+             ) -> Tuple[Array, Array]:
+    """The held experts' part of the layer's output, and per row the
+    number of held experts it was routed to.
+
+    Routes over all ``n_experts``; each held expert runs on every row
+    (its projections through ``apply_linear``, so an epitome expert runs
+    the fused int8 kernel) and its output is combined with the row's
+    routing weight, 0 where the row did not pick it.  Per token and
+    dropless: no capacity, so no row changes another's result."""
     B, S, d = x.shape
     act = act_fn(cfg.act)
     x2 = x.reshape(-1, d)
-    weights, experts = _route(x2, params["router"], cfg)       # (T,k)
-    comb = jnp.zeros((x2.shape[0], cfg.n_experts), jnp.float32)
-    comb = comb.at[jnp.arange(x2.shape[0])[:, None], experts].add(weights)
-    # all experts on all tokens, masked combine
-    g = jnp.einsum("td,edf->tef", x2, params["w_gate"].astype(x.dtype))
-    u = jnp.einsum("td,edf->tef", x2, params["w_up"].astype(x.dtype))
-    h = act(g) * u                                             # (T,E,ff)
-    o = jnp.einsum("tef,efd->ted", h, params["w_down"].astype(x.dtype))
-    y = jnp.einsum("ted,te->td", o.astype(jnp.float32), comb)
-    return y.reshape(B, S, d).astype(x.dtype)
+    lo, hi = cfg.held_experts
+    with jax.named_scope("epim.moe.route"):
+        comb = route(x2, params["router"], cfg)[:, lo:hi]     # (T, E_held)
+    lcs = {name: _site_ep(cfg, name, down, prefix)[2]
+           for name, down in _EXPERT_SITES}
+
+    def expert(y, inp):
+        p, w = inp
+        g = apply_linear(p["w_gate"], x2, lcs["w_gate"])
+        u = apply_linear(p["w_up"], x2, lcs["w_up"])
+        o = apply_linear(p["w_down"], act(g) * u, lcs["w_down"])
+        return y + o.astype(jnp.float32) * w[:, None], None
+
+    experts = {name: params[name] for name, _ in _EXPERT_SITES}
+    y, _ = jax.lax.scan(expert, jnp.zeros((x2.shape[0], d), jnp.float32),
+                        (experts, comb.T))
+    routed = jnp.sum(comb > 0, axis=-1, dtype=jnp.int32).reshape(B, S)
+    return y.reshape(B, S, d).astype(x.dtype), routed
+
+
+def moe_dense(params: dict, x: Array, cfg: ModelConfig,
+              prefix: str = "") -> Array:
+    """``moe_held``'s output alone."""
+    return moe_held(params, x, cfg, prefix)[0]
 
 
 # ---------------------------------------------------------------------------
-# Path 1: shard_map dispatch with all_to_all (training / prefill)
+# Capacity dispatch: shard_map with all_to_all (training / prefill at scale)
 # ---------------------------------------------------------------------------
 def _local_pack(x2, weights, experts, n_dest: int, cap: int, repl: int,
                 n_experts: int):
@@ -125,7 +176,8 @@ def _local_unpack(recv_y, info, T: int, d: int):
     return y
 
 
-def moe_dispatch(params: dict, x: Array, cfg: ModelConfig) -> Array:
+def moe_dispatch(params: dict, x: Array, cfg: ModelConfig,
+                 prefix: str = "") -> Array:
     """shard_map + all_to_all expert parallelism over the batch axes."""
     mesh = get_mesh()
     assert mesh is not None
@@ -150,9 +202,13 @@ def moe_dispatch(params: dict, x: Array, cfg: ModelConfig) -> Array:
         return jax.lax.with_sharding_constraint(
             wE, jax.sharding.NamedSharding(mesh, spec))
 
-    w_gate = slots(params["w_gate"].astype(x.dtype))
-    w_up = slots(params["w_up"].astype(x.dtype))
-    w_down = slots(params["w_down"].astype(x.dtype), transpose=True)
+    assert cfg.held_experts == (0, E), "dispatch needs every expert held"
+    w = {name: _stacked_weight(params[name],
+                               _site_ep(cfg, name, down, prefix)[2])
+         for name, down in _EXPERT_SITES}
+    w_gate = slots(w["w_gate"].astype(x.dtype))
+    w_up = slots(w["w_up"].astype(x.dtype))
+    w_down = slots(w["w_down"].astype(x.dtype), transpose=True)
 
     def local_fn(x_l, router, wg_l, wu_l, wd_l):
         # x_l: (B_l, S, d); w*_l: (1, d, ff/tp) — this shard's expert slot
@@ -185,20 +241,26 @@ def moe_dispatch(params: dict, x: Array, cfg: ModelConfig) -> Array:
     )(x, params["router"], w_gate, w_up, w_down)
 
 
-def moe_ffn(params: dict, x: Array, cfg: ModelConfig, *,
-            force_dense: bool = False) -> Array:
-    """Entry point: picks the execution path."""
+def takes_dispatch(cfg: ModelConfig, B: int, S: int) -> bool:
+    """Whether ``moe_ffn`` runs the capacity dispatch for a (B, S) input
+    under the installed mesh: the batch divides over the data shards, the
+    shards map onto the experts, and there are enough local tokens to fill
+    capacity buffers."""
     mesh = get_mesh()
-    if mesh is None or force_dense:
-        return moe_dense(params, x, cfg)
+    if mesh is None or "moe" not in cfg.ffn_pattern:
+        return False
     dp_axes = tuple(a for a in BATCH_AXES if a in mesh.axis_names)
     n_dp = math.prod(mesh.shape[a] for a in dp_axes)
-    B, S, _ = x.shape
-    # dispatch path needs: batch divisible over the data shards, an integer
-    # replica count, and enough local tokens to fill capacity buffers;
-    # otherwise use the dense-masked path (decode / tiny batches)
     if B % n_dp != 0 or n_dp % cfg.n_experts != 0:
-        return moe_dense(params, x, cfg)
-    if (B // n_dp) * S < cfg.n_experts and not cfg.moe_decode_dispatch:
-        return moe_dense(params, x, cfg)    # decode default: weights resident
-    return moe_dispatch(params, x, cfg)
+        return False
+    return (B // n_dp) * S >= cfg.n_experts
+
+
+def moe_ffn(params: dict, x: Array, cfg: ModelConfig, prefix: str = "",
+            *, force_dense: bool = False) -> Array:
+    """Entry point: the capacity dispatch where ``takes_dispatch`` says so
+    (multi-device meshes), else the per-token path."""
+    B, S, _ = x.shape
+    if not force_dense and takes_dispatch(cfg, B, S):
+        return moe_dispatch(params, x, cfg, prefix)
+    return moe_dense(params, x, cfg, prefix)

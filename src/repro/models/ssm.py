@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 
 from ..core.layers import apply_linear, init_linear
-from .common import act_fn, shard, BATCH_AXES, TENSOR_AXIS
+from .common import (act_fn, init_rms_norm, rms_norm, shard, BATCH_AXES,
+                     TENSOR_AXIS)
 from .config import ModelConfig, layer_name as _nm
 
 Array = jax.Array
@@ -293,10 +294,14 @@ def rwkv_channel_mix(p: dict, x: Array, cfg: ModelConfig,
 def init_mamba(key: Array, cfg: ModelConfig, prefix: str = "") -> dict:
     d = cfg.d_model
     di, ds, dc = cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_d_conv
-    dt_rank = max(1, d // 16)
+    dt_rank = cfg.dt_rank
     ks = jax.random.split(key, 7)
     dtp = cfg.pdtype
-    return {
+    norms = ({"dt_norm": init_rms_norm(dt_rank, dtp),
+              "b_norm": init_rms_norm(ds, dtp),
+              "c_norm": init_rms_norm(ds, dtp)}
+             if cfg.mamba_dtbc_norm else {})
+    return {**norms,
         "in_proj": init_linear(ks[0], d, 2 * di,
                                cfg.ep(d, 2 * di, _nm(prefix, "in_proj")),
                                dtype=dtp),
@@ -332,17 +337,22 @@ def mamba_mix(p: dict, x: Array, cfg: ModelConfig,
               state: Optional[Tuple[Array, Array]] = None,
               chunk: int = 0, prefix: str = "",
               valid_len: Optional[Array] = None):
-    chunk = chunk or cfg.mamba_chunk
-    """Mamba block.  state = (conv buffer (B, dc-1, di), h (B, di, ds)).
+    """Mamba block.  state = (conv buffer (B, dc-1, di), h (B, ds, di)):
+    the carried ``h`` keeps d_inner on its last (lane) axis, since a
+    d_state of 16 there would pad to a TPU's 128 lanes; the scan itself
+    runs on (.., di, ds).  With ``cfg.mamba_dtbc_norm`` dt, B and C are
+    RMS-normalised after ``x_proj`` (Jamba).  Every op from the conv to
+    the gate runs under the ``epim.mamba`` scope.
 
     ``valid_len`` (traced scalar) marks a right-padded prefill: pad
     positions get dt forced to 0 so their scan elements are the exact
     identity (dA=exp(0)=1, dBx=0 — the same trick the chunk padding
     already relies on), and the carried conv window is gathered ending at
     the last real token instead of the last position."""
+    chunk = chunk or cfg.mamba_chunk
     B, S, d = x.shape
     di, ds, dc = cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_d_conv
-    dt_rank = max(1, d // 16)
+    dt_rank = cfg.dt_rank
     xz = apply_linear(p["in_proj"], x,
                       cfg.ep(d, 2 * di, _nm(prefix, "in_proj")))
     xi, z = jnp.split(xz, 2, axis=-1)
@@ -353,7 +363,21 @@ def mamba_mix(p: dict, x: Array, cfg: ModelConfig,
         conv_buf = jnp.zeros((B, dc - 1, di), xi.dtype)
         h0 = jnp.zeros((B, di, ds), jnp.float32)
     else:
-        conv_buf, h0 = state
+        conv_buf, h0 = state[0], jnp.swapaxes(state[1], -1, -2)
+    with jax.named_scope("epim.mamba"):
+        y, new_conv, h_last = _mamba_core(p, xi, z, conv_buf, h0, cfg, chunk,
+                                          prefix, valid_len)
+    out = apply_linear(p["out_proj"], y,
+                       cfg.ep(di, d, _nm(prefix, "out_proj")))
+    return out, (new_conv, jnp.swapaxes(h_last, -1, -2))
+
+
+def _mamba_core(p, xi, z, conv_buf, h0, cfg: ModelConfig, chunk: int,
+                prefix: str, valid_len):
+    """Conv, selective scan and gate: (y (B, S, di), conv window, h
+    (B, di, ds))."""
+    B, S, di = xi.shape
+    ds, dc, dt_rank = cfg.mamba_d_state, cfg.mamba_d_conv, cfg.dt_rank
     # causal depthwise conv along S
     xpad = jnp.concatenate([conv_buf.astype(xi.dtype), xi], axis=1)
     cw = p["conv_w"].astype(xi.dtype)
@@ -372,6 +396,10 @@ def mamba_mix(p: dict, x: Array, cfg: ModelConfig,
     proj = apply_linear(p["x_proj"], xc,
                         cfg.ep(di, dt_rank + 2 * ds, _nm(prefix, "x_proj")))
     dt, Bp, Cp = jnp.split(proj, [dt_rank, dt_rank + ds], axis=-1)
+    if cfg.mamba_dtbc_norm:
+        dt = rms_norm(dt, p["dt_norm"], cfg.norm_eps)
+        Bp = rms_norm(Bp, p["b_norm"], cfg.norm_eps)
+        Cp = rms_norm(Cp, p["c_norm"], cfg.norm_eps)
     dt = jax.nn.softplus(apply_linear(
         p["dt_proj"], dt, cfg.ep(dt_rank, di, _nm(prefix, "dt_proj"))))
     dt = shard(dt, BATCH_AXES, None, TENSOR_AXIS)
@@ -418,13 +446,12 @@ def mamba_mix(p: dict, x: Array, cfg: ModelConfig,
                              unroll=n if UNROLL_CHUNKS else 1)
     y = y.transpose(1, 0, 2, 3).reshape(B, n * L, di)[:, :S]
     y = y + xc.astype(jnp.float32) * p["D"][None, None]
-    y = (y.astype(x.dtype)) * jax.nn.silu(z)
-    out = apply_linear(p["out_proj"], y,
-                       cfg.ep(di, d, _nm(prefix, "out_proj")))
-    return out, (new_conv, h_last)
+    y = (y.astype(z.dtype)) * jax.nn.silu(z)
+    return y, new_conv, h_last
 
 
 def init_mamba_state(cfg: ModelConfig, batch: int, n: int = 1):
+    """(conv (n, B, dc-1, di), h (n, B, ds, di)): d_inner last."""
     di, ds, dc = cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_d_conv
     return (jnp.zeros((n, batch, dc - 1, di), cfg.cdtype),
-            jnp.zeros((n, batch, di, ds), jnp.float32))
+            jnp.zeros((n, batch, ds, di), jnp.float32))

@@ -58,8 +58,9 @@ MODES = ("reconstruct", "wrapped", "folded", "kernel")
 # importable without jax; a registry cross-check test guards against drift.
 LM_SMOKE_SUFFIX = "-smoke"
 LM_ARCHS = ("rwkv6-7b", "phi3.5-moe-42b-a6.6b", "grok-1-314b",
-            "jamba-1.5-large-398b", "qwen2-72b", "qwen1.5-110b",
-            "gemma2-2b", "deepseek-67b", "musicgen-large", "internvl2-76b")
+            "jamba-1.5-large-398b", "jamba2-mini", "jamba2-mini-ep2",
+            "qwen2-72b", "qwen1.5-110b", "gemma2-2b", "deepseek-67b",
+            "musicgen-large", "internvl2-76b")
 
 
 def is_lm_arch(arch: str) -> bool:

@@ -86,9 +86,12 @@ def lm_layers(cfg) -> List[LayerShape]:
     ``L{i}/ffn/w_gate``, ...) so a plan's layer names key directly into
     ``ModelConfig.layer_config`` and the vmapped tree prepack.  rows/cols
     are the projection's virtual (fan-in, fan-out) = (word lines, bit
-    lines); kind="fc" (one activation round per token).  Small vectors
-    (norms, LoRAs, mu's, conv buffers) and stacked MoE expert tensors are
-    not epitomizable sites and do not appear.
+    lines); kind="fc" (one activation round per token).  An MoE layer's
+    expert projections are one site each (``L{i}/ffn/w_gate``, ...), of one
+    expert's shape: their params stack the held experts on an axis after
+    the group axis, and prepack and plans key on that one name.  Small
+    vectors (norms, LoRAs, mu's, conv buffers) and the router are not
+    epitomizable sites and do not appear.
     """
     d = cfg.d_model
     hd = cfg.head_dim or d // cfg.n_heads
@@ -115,14 +118,12 @@ def lm_layers(cfg) -> List[LayerShape]:
                     fc(f"{p}/dt_proj", dt_rank, di),
                     fc(f"{p}/out_proj", di, d)]
         q = f"L{i}/ffn"
-        if ffn_kind == "dense":
+        if ffn_kind in ("dense", "moe"):
             out += [fc(f"{q}/w_gate", d, ff), fc(f"{q}/w_up", d, ff),
                     fc(f"{q}/w_down", ff, d)]
         elif ffn_kind == "rwkv_ffn":
             out += [fc(f"{q}/wk", d, ff), fc(f"{q}/wv", ff, d),
                     fc(f"{q}/wr", d, d)]
-        # moe experts are stacked (E, d, ff) dense tensors — not epitome
-        # sites today, so they stay out of the inventory
     return out
 
 

@@ -299,11 +299,17 @@ def test_bucketed_prefill_bounds_retraces(engine_factory):
     assert eng.stats["prefill_traces"] == 2
 
 
-def test_moe_arch_prefills_exact_length():
+def test_moe_arch_prefills_exact_length(monkeypatch):
     """MoE capacity routing couples batch rows (pads would consume expert
-    queue ranks) — those arches must bypass bucketing."""
-    moe = EpimEngine(get_smoke_config("phi3.5-moe-42b-a6.6b"), None,
-                     capacity=1, max_len=32)
+    queue ranks) — where a mesh would run it, prompts bypass bucketing.
+    The per-token MoE path (one device) is row-local and buckets."""
+    from repro.models import moe as moe_mod
+    cfg = get_smoke_config("phi3.5-moe-42b-a6.6b")
+    per_token = EpimEngine(cfg, None, capacity=1, max_len=32)
+    assert per_token.bucket_prompts and per_token._bucket(5) == 8
+    monkeypatch.setattr(moe_mod, "takes_dispatch",
+                        lambda cfg, *a: "moe" in cfg.ffn_pattern)
+    moe = EpimEngine(cfg, None, capacity=1, max_len=32)
     assert not moe.bucket_prompts
     assert moe._bucket(5) == 5
     ssm = EpimEngine(get_smoke_config("rwkv6-7b"), None,

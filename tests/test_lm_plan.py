@@ -48,18 +48,25 @@ class TestLMInventory:
             assert arch + LM_SMOKE_SUFFIX in INVENTORIES, arch
 
     @pytest.mark.parametrize("arch", ["rwkv6-7b", "gemma2-2b",
-                                      "jamba-1.5-large-398b"])
+                                      "jamba-1.5-large-398b",
+                                      "jamba2-mini-ep2"])
     def test_names_and_shapes_match_param_tree(self, arch):
         """Every inventory row names a real param-tree path whose dense
         weight has exactly the inventoried (rows, cols) — stacked over the
-        leading group axis."""
+        leading group axis, and an MoE layer's expert sites over the held
+        experts after it."""
         cfg = get_smoke_config(arch)
         inv = lm_layers(cfg)
         assert inv, arch
         shapes = jax.eval_shape(lambda: lm.init_params(KEY, cfg))
+        lo, hi = cfg.held_experts
         for l in inv:
             leaf = _tree_get(shapes["groups"], l.name)
-            assert leaf["W"].shape == (cfg.n_groups, l.rows, l.cols), l.name
+            i = int(l.name.split("/")[0][1:])
+            held = ((hi - lo,) if "/ffn/" in l.name
+                    and cfg.full_pattern[i][1] == "moe" else ())
+            assert leaf["W"].shape == (cfg.n_groups, *held, l.rows,
+                                       l.cols), l.name
 
     def test_smoke_inventory_builder(self):
         names = [l.name for l in inventory_for(SMOKE)()]

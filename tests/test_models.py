@@ -178,7 +178,8 @@ def test_input_specs_complete():
             assert spec, (arch, shape)
 
 
-def _decode_step_stacked(params, state, token, pos, cfg, page_table=None):
+def _decode_step_stacked(params, state, token, pos, cfg, page_table=None,
+                         routed=False):
     """The layer scan as it was before the state rode the carry: the
     pool scanned as xs and the new state stacked as ys.  The reference
     for the in-place ``lm.decode_step``."""
@@ -187,14 +188,17 @@ def _decode_step_stacked(params, state, token, pos, cfg, page_table=None):
 
     def scan_fn(x, gs):
         group_params, group_state = gs
-        return decode_group(group_params, group_state, x, pos, cfg,
-                            page_table=page_table)
+        x, new_state, n = decode_group(group_params, group_state, x, pos,
+                                       cfg, page_table=page_table)
+        return x, (new_state, n)
 
-    x, new_states = jax.lax.scan(scan_fn, x, (params["groups"], state),
-                                 unroll=min(lm.SCAN_UNROLL, cfg.n_groups))
+    x, (new_states, n) = jax.lax.scan(
+        scan_fn, x, (params["groups"], state),
+        unroll=min(lm.SCAN_UNROLL, cfg.n_groups))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params.get("head", params["embed"].T if cfg.tie_embeddings else None)
-    return unembed(x, head, cfg.logit_softcap), new_states
+    logits = unembed(x, head, cfg.logit_softcap)
+    return (logits, new_states, n.sum(0)) if routed else (logits, new_states)
 
 
 def _filled_pool(cfg, capacity, page_size):
@@ -225,8 +229,9 @@ def _assert_trees_equal(a, b):
 
 
 @pytest.mark.parametrize("arch,page_size", [
-    ("rwkv6-7b", 0), ("qwen2-72b", 8), ("jamba-1.5-large-398b", 0)],
-    ids=["rwkv6", "qwen2-paged", "jamba-mamba"])
+    ("rwkv6-7b", 0), ("qwen2-72b", 8), ("jamba-1.5-large-398b", 0),
+    ("jamba2-mini-ep2", 8)],
+    ids=["rwkv6", "qwen2-paged", "jamba-mamba", "jamba2-hybrid-paged"])
 @pytest.mark.parametrize("k", [0, 1, 4], ids=["step", "scan1", "scan4"])
 def test_decode_in_place_bit_identical(monkeypatch, arch, page_size, k):
     """Writing each group's new state into the carried pool gives the same

@@ -185,12 +185,20 @@ def test_chunked_prefill_respects_recurrence_alignment():
     assert eng.stats["prefill_chunks"] == 2
 
 
-def test_chunking_disabled_where_it_would_change_bits():
-    """MoE couples every token through capacity routing; int8 KV caches
-    would make chunk 2 attend dequantized rows the one-shot path attends
-    fresh.  Both must fall back to whole-prompt prefill."""
-    moe = EpimEngine(get_smoke_config("phi3.5-moe-42b-a6.6b"), None,
-                     capacity=1, max_len=32, prefill_chunk=8)
+def test_chunking_disabled_where_it_would_change_bits(monkeypatch):
+    """An MoE layer run as a capacity dispatch couples every token through
+    its capacity routing; int8 KV caches would make chunk 2 attend
+    dequantized rows the one-shot path attends fresh.  Both must fall back
+    to whole-prompt prefill.  The per-token MoE path of one device is
+    row-local and chunks."""
+    from repro.models import moe as moe_mod
+    cfg = get_smoke_config("phi3.5-moe-42b-a6.6b")
+    per_token = EpimEngine(cfg, None, capacity=1, max_len=32,
+                           prefill_chunk=8)
+    assert per_token.chunk == 8
+    monkeypatch.setattr(moe_mod, "takes_dispatch",
+                        lambda cfg, *a: "moe" in cfg.ffn_pattern)
+    moe = EpimEngine(cfg, None, capacity=1, max_len=32, prefill_chunk=8)
     assert moe.chunk == 0
     cfg8 = dataclasses.replace(get_smoke_config("qwen2-72b"),
                                kv_cache_bits=8)

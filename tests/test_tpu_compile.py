@@ -175,3 +175,56 @@ def test_decode_writes_state_pool_in_place(one_chip):
     assert not pool_shapes & set(allocs), allocs
     copies = re.findall(r"= (\w+\[[\d,]*\])\{[^}]*\} copy\(", text)
     assert s not in copies, copies
+
+
+def test_jamba2_share_decode_step(one_chip, monkeypatch):
+    """The engine's decode dispatch for Jamba2-Mini's one-chip share at
+    published widths (8 layers, 8 of 16 experts, 64 slots, 2560 tokens in
+    pages of 16): the held experts' projections run the fused int8 kernel
+    (Mosaic custom calls under the ``epim.moe`` scope), and neither the
+    Mamba state nor the paged KV pool is allocated afresh or copied whole:
+    the donated pool is written in place."""
+    from repro.configs import get_config
+    from repro.launch import engine
+    from repro.models import lm
+    from repro.models.kv_pool import SlotStatePool
+    # the kernels pick Mosaic over interpret mode from the default backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    C, max_len = 64, 2560
+    cfg = get_config("jamba2-mini-ep2", "kernel-q3")
+
+    def sds(leaf):
+        return _sds(leaf.shape, leaf.dtype, one_chip)
+    params = jax.tree.map(sds, jax.eval_shape(
+        lambda key: lm.prepack_params(lm.init_params(key, cfg), cfg),
+        jax.random.PRNGKey(0)))
+    assert params["groups"]["L1"]["ffn"]["w_gate"]["Eq"].shape == (
+        1, 8, 1024, 14336)
+    pool = jax.tree.map(sds, jax.eval_shape(
+        lambda: SlotStatePool(cfg, C, max_len, page_size=16).tree))
+    rows = lambda *shape, dtype=jnp.int32: _sds((C,) + shape, dtype, one_chip)
+    text = engine._decode_multi.lower(
+        params, pool, rows(1), rows(), rows(2, dtype=jnp.uint32),
+        rows(dtype=jnp.float32), rows(), rows(max_len // 16), cfg=cfg, k=1
+    ).compile().as_text()
+
+    # int8 codes each kernel call reads: the 4 dense FFNs' gate and up
+    # (1024 x 14336) and down (3584 x 4096) and, once each in the expert
+    # scan's body, the 4 MoE layers' — 16 and 8 with the experts on the
+    # kernel, 8 and 4 without; 7 Mamba in_proj and out_proj; attention
+    codes = [re.search(r"s8\[[\d,]*\]", l).group(0)
+             for l in text.splitlines() if "tpu_custom_call" in l]
+    assert sorted(set(codes)) == ["s8[1024,14336]", "s8[1024,16384]",
+                                  "s8[1024,4096]", "s8[2048,4096]",
+                                  "s8[3584,4096]", "s8[4096,256]"], codes
+    assert codes.count("s8[1024,14336]") == 16
+    assert codes.count("s8[3584,4096]") == 8
+    assert codes.count("s8[1024,16384]") == codes.count("s8[2048,4096]") == 7
+    h, k = _hlo_shape(pool["L0"]["h"]), _hlo_shape(pool["L4"]["k"])
+    assert (h, k) == ("f32[1,64,16,8192]", "bf16[1,10241,16,8,128]")
+    pool_shapes = {_hlo_shape(l) for l in jax.tree.leaves(pool)}
+    allocs = re.findall(r"= (\w+\[[\d,]*\])\{[^}]*\} custom-call\(\)"
+                        r"[^\n]*AllocateBuffer", text)
+    assert not pool_shapes & set(allocs), allocs
+    copies = re.findall(r"= (\w+\[[\d,]*\])\{[^}]*\} copy\(", text)
+    assert not pool_shapes & set(copies), copies
