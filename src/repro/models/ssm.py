@@ -12,7 +12,8 @@ contract): within a chunk of length L all exponents are *non-positive*
 with A_t = exp(cumsum_log w)_t.
 
 Mamba (arXiv:2312.00752, as used in jamba): selective scan with
-input-dependent (dt, B, C); chunked associative scan over the sequence.
+input-dependent (dt, B, C), run token by token on the state in windows of
+cfg.mamba_chunk tokens.
 """
 from __future__ import annotations
 
@@ -34,23 +35,29 @@ Array = jax.Array
 # bodies are otherwise counted once).
 UNROLL_CHUNKS = False
 
+# Tokens per iteration of Mamba's token-serial scan: 1, 4, 8 and 16 timed
+# within 4% of each other on a TPU v5e at Jamba2-Mini's widths, 16 the
+# fastest (PERF.md).
+TOKEN_UNROLL = 16
+
 
 def recurrence_alignment(cfg: ModelConfig) -> int:
     """Smallest chunk granularity at which a prefill can be split without
     changing any recurrent layer's bits (launch/engine.py chunked prefill).
 
-    Both mixers evaluate their recurrence in fixed internal windows
-    (cfg.rwkv_chunk / cfg.mamba_chunk) whose carried state crosses window
-    boundaries through non-associative fp arithmetic — exp(a)·exp(b) is
-    not exp(a+b) in fp32, and the associative-scan tree reshapes with the
-    window count.  Splitting a prompt anywhere *except* a window boundary
-    therefore changes bits.  An engine chunk that is a common multiple of
-    every recurrence window present makes each engine chunk an integer
-    number of internal windows, so the chunked evaluation performs the
-    identical sequence of window scans and state carries as the one-shot
-    prefill (a padded final chunk matches one-shot's own zero-padded last
-    window: pads contribute exact identity scan elements under valid_len
-    masking).  Attention-only stacks may split anywhere (returns 1)."""
+    RWKV evaluates its recurrence in fixed internal windows
+    (cfg.rwkv_chunk) whose carried state crosses window boundaries through
+    non-associative fp arithmetic — exp(a)·exp(b) is not exp(a+b) in fp32
+    — so splitting a prompt anywhere *except* a window boundary changes
+    bits.  An engine chunk that is a common multiple of every recurrence
+    window present makes each engine chunk an integer number of internal
+    windows, so the chunked evaluation performs the identical sequence of
+    window scans and state carries as the one-shot prefill (a padded final
+    chunk matches one-shot's own zero-padded last window: pads are exact
+    identity steps under valid_len masking).  Mamba's scan runs token by
+    token, the same arithmetic wherever its windows (cfg.mamba_chunk)
+    fall, so that argument no longer holds for it; its window is still
+    counted here.  Attention-only stacks may split anywhere (returns 1)."""
     align = 1
     for kind, _ in cfg.full_pattern:
         if kind == "mamba":
@@ -322,33 +329,24 @@ def init_mamba(key: Array, cfg: ModelConfig, prefix: str = "") -> dict:
     }
 
 
-def _mamba_scan_chunk(dA, dBx, h0):
-    """Associative scan within a chunk.  dA/dBx: (B, L, di, ds)."""
-    def combine(a, b):
-        A1, B1 = a
-        A2, B2 = b
-        return A1 * A2, B1 * A2 + B2
-    A, Bs = jax.lax.associative_scan(combine, (dA, dBx), axis=1)
-    h = A * h0[:, None] + Bs
-    return h, h[:, -1]
-
-
 def mamba_mix(p: dict, x: Array, cfg: ModelConfig,
               state: Optional[Tuple[Array, Array]] = None,
               chunk: int = 0, prefix: str = "",
               valid_len: Optional[Array] = None):
     """Mamba block.  state = (conv buffer (B, dc-1, di), h (B, ds, di)):
-    the carried ``h`` keeps d_inner on its last (lane) axis, since a
-    d_state of 16 there would pad to a TPU's 128 lanes; the scan itself
-    runs on (.., di, ds).  With ``cfg.mamba_dtbc_norm`` dt, B and C are
-    RMS-normalised after ``x_proj`` (Jamba).  Every op from the conv to
-    the gate runs under the ``epim.mamba`` scope.
+    ``h`` keeps d_inner on its last (lane) axis, since a d_state of 16
+    there would pad to a TPU's 128 lanes, and the selective scan runs
+    token by token on that same (B, ds, di) layout, from the pool to the
+    pool.  With ``cfg.mamba_dtbc_norm`` dt, B and C are RMS-normalised
+    after ``x_proj`` (Jamba).  Every op from the conv to the gate runs
+    under the ``epim.mamba`` scope, the recurrence under
+    ``epim.mamba.scan``.
 
     ``valid_len`` (traced scalar) marks a right-padded prefill: pad
-    positions get dt forced to 0 so their scan elements are the exact
-    identity (dA=exp(0)=1, dBx=0 — the same trick the chunk padding
-    already relies on), and the carried conv window is gathered ending at
-    the last real token instead of the last position."""
+    positions get dt forced to 0 so their steps are the exact identity
+    (exp(0)=1 times h, plus 0 — the same trick the chunk padding already
+    relies on), and the carried conv window is gathered ending at the
+    last real token instead of the last position."""
     chunk = chunk or cfg.mamba_chunk
     B, S, d = x.shape
     di, ds, dc = cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_d_conv
@@ -361,21 +359,21 @@ def mamba_mix(p: dict, x: Array, cfg: ModelConfig,
 
     if state is None:
         conv_buf = jnp.zeros((B, dc - 1, di), xi.dtype)
-        h0 = jnp.zeros((B, di, ds), jnp.float32)
+        h0 = jnp.zeros((B, ds, di), jnp.float32)
     else:
-        conv_buf, h0 = state[0], jnp.swapaxes(state[1], -1, -2)
+        conv_buf, h0 = state
     with jax.named_scope("epim.mamba"):
         y, new_conv, h_last = _mamba_core(p, xi, z, conv_buf, h0, cfg, chunk,
                                           prefix, valid_len)
     out = apply_linear(p["out_proj"], y,
                        cfg.ep(di, d, _nm(prefix, "out_proj")))
-    return out, (new_conv, jnp.swapaxes(h_last, -1, -2))
+    return out, (new_conv, h_last)
 
 
 def _mamba_core(p, xi, z, conv_buf, h0, cfg: ModelConfig, chunk: int,
                 prefix: str, valid_len):
     """Conv, selective scan and gate: (y (B, S, di), conv window, h
-    (B, di, ds))."""
+    (B, ds, di))."""
     B, S, di = xi.shape
     ds, dc, dt_rank = cfg.mamba_d_state, cfg.mamba_d_conv, cfg.dt_rank
     # causal depthwise conv along S
@@ -406,45 +404,39 @@ def _mamba_core(p, xi, z, conv_buf, h0, cfg: ModelConfig, chunk: int,
     if valid_len is not None:
         dt = jnp.where((jnp.arange(S) < valid_len)[None, :, None], dt,
                        jnp.zeros((), dt.dtype))
-    A = -jnp.exp(p["A_log"])                               # (di, ds)
+    A_t = -jnp.exp(p["A_log"]).T                           # (ds, di)
 
-    # chunked scan over the sequence.  Discretization (dA, dBx — the
-    # (.., di, ds) tensors) AND the C-contraction happen inside the chunk
-    # body, so nothing of shape (B, S, di, ds) ever materializes: jamba at
-    # d_inner=16384 would otherwise need ~16x the activation bytes.
+    # the recurrence, token by token on h (B, ds, di) in windows of L
+    # tokens: nothing of shape (.., L, ds, di) is ever built, so each
+    # token reads and writes h once
     L = min(chunk, S)
     n = -(-S // L)
     pad = n * L - S
 
-    def chunks(t, fill=0.0):
+    def chunks(t):                                         # (n, L, B, ..)
         if pad:
-            t = jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2),
-                        constant_values=fill)
+            t = jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
         return t.reshape((B, n, L) + t.shape[2:]).transpose(
-            (1, 0, 2) + tuple(range(3, t.ndim + 1)))
+            (1, 2, 0) + tuple(range(3, t.ndim + 1)))
 
-    dt_c = chunks(dt.astype(jnp.float32))                  # (n,B,L,di); pad 0
-    x_c = chunks(xc.astype(jnp.float32))
-    B_c = chunks(Bp.astype(jnp.float32))
-    C_c = chunks(Cp.astype(jnp.float32))
+    def step(h, inp):
+        dt_t, x_t, b_t, c_t = inp                          # (B, di), (B, ds)
+        h = (jnp.exp(dt_t[:, None, :] * A_t) * h           # pad: exp(0)=1
+             + (dt_t * x_t)[:, None, :] * b_t[:, :, None])  # pad: 0
+        return h, jnp.sum(h * c_t[:, :, None], axis=1)
 
     @jax.checkpoint
-    def chunk_fn(h, dtk, xk, bk, ck):
-        # nested remat: the associative scan's backward otherwise saves all
-        # O(log L) tree levels of (B, L, di, ds) fp32 — tens of GB at
-        # jamba's d_inner; recomputing one chunk's forward is cheap
-        dA = jnp.exp(dtk[..., None] * A[None, None])       # pad: exp(0)=1
-        dBx = (dtk * xk)[..., None] * bk[:, :, None]       # pad: 0
-        hs, h_last = _mamba_scan_chunk(dA, dBx, h)
-        y_c = jnp.einsum("bldn,bln->bld", hs, ck)
-        return h_last, y_c
+    def chunk_fn(h, inp):
+        # nested remat: the backward of one window keeps its L states
+        # (B, ds, di) and no more; recomputing one window's forward is cheap
+        return jax.lax.scan(step, h, inp,
+                            unroll=L if UNROLL_CHUNKS else TOKEN_UNROLL)
 
-    def body(h, inp):
-        return chunk_fn(h, *inp)
-
-    h_last, y = jax.lax.scan(body, h0, (dt_c, x_c, B_c, C_c),
-                             unroll=n if UNROLL_CHUNKS else 1)
-    y = y.transpose(1, 0, 2, 3).reshape(B, n * L, di)[:, :S]
+    seq = tuple(chunks(t.astype(jnp.float32)) for t in (dt, xc, Bp, Cp))
+    with jax.named_scope("epim.mamba.scan"):
+        h_last, y = jax.lax.scan(chunk_fn, h0, seq,
+                                 unroll=n if UNROLL_CHUNKS else 1)
+    y = y.transpose(2, 0, 1, 3).reshape(B, n * L, di)[:, :S]
     y = y + xc.astype(jnp.float32) * p["D"][None, None]
     y = (y.astype(z.dtype)) * jax.nn.silu(z)
     return y, new_conv, h_last

@@ -18,7 +18,7 @@ import pytest
 
 from repro.configs import get_config, get_smoke_config
 from repro.launch import engine as eng
-from repro.models import lm, moe
+from repro.models import lm, moe, ssm
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -40,8 +40,9 @@ def test_engine_prefill_decode_matches_reference():
     slot pool (pages of 8), then decode steps through the page table, as
     ``EpimEngine`` runs them, against the reference's full forward over
     the same tokens.  Tolerance 2e-3 on logits of magnitude about 4: the
-    two sum in other orders through 16 layers (the reference's Mamba runs
-    token by token, the program's as an associative scan in windows; the
+    two sum in other orders through 16 layers (both Mamba scans run token
+    by token, the reference's on (B, d_inner, d_state) with an einsum over
+    d_state, the program's on (B, d_state, d_inner) with a sum; the
     reference's A is -(1..d_state), the program's -exp(log(1..d_state)))."""
     cfg = dataclasses.replace(get_smoke_config("jamba2-mini-ep2", "kernel-q3"),
                               compute_dtype="float32", mamba_chunk=8)
@@ -191,3 +192,84 @@ def test_share_config():
     assert share.mamba_dtbc_norm and not share.rope
     assert share.full_pattern[4] == ("attn", "dense")
     assert share.full_pattern[1] == ("mamba", "moe")
+
+
+def _mamba_plain(p, x, S):
+    """Mamba in plain float32, token by token as the reference's ``step``
+    runs it, on h laid out (B, di, ds): (out (B, S, d), conv window, h
+    (B, ds, di)) over the first ``S`` tokens of ``x``."""
+    hp = jax.lax.Precision.HIGHEST
+    dot = lambda a, w: jnp.matmul(a, w, precision=hp)
+    norm = lambda t, w: t * jax.lax.rsqrt(
+        jnp.mean(t * t, -1, keepdims=True) + 1e-6) * (1.0 + w)
+    x = x[:, :S]
+    B = x.shape[0]
+    dc, di = p["conv_w"].shape
+    ds = p["A_log"].shape[1]
+    xi, z = jnp.split(dot(x, p["in_proj"]["W"]), 2, axis=-1)
+    xpad = jnp.concatenate([jnp.zeros((B, dc - 1, di)), xi], axis=1)
+    xc = jax.nn.silu(sum(xpad[:, i:i + S] * p["conv_w"][i] for i in range(dc))
+                     + p["conv_b"])
+    R = p["dt_proj"]["W"].shape[0]
+    dt, Bp, Cp = jnp.split(dot(xc, p["x_proj"]["W"]), [R, R + ds], axis=-1)
+    dt = jax.nn.softplus(dot(norm(dt, p["dt_norm"]), p["dt_proj"]["W"])
+                         + p["dt_proj"]["b"])
+    Bp, Cp = norm(Bp, p["b_norm"]), norm(Cp, p["c_norm"])
+    A = -jnp.exp(p["A_log"])
+
+    def step(h, t):
+        dt_t, x_t, b_t, c_t = t
+        h = jnp.exp(dt_t[..., None] * A) * h + (dt_t * x_t)[..., None] \
+            * b_t[:, None]
+        return h, jnp.einsum("bdn,bn->bd", h, c_t, precision=hp)
+
+    seq = tuple(jnp.moveaxis(t, 1, 0) for t in (dt, xc, Bp, Cp))
+    h, y = jax.lax.scan(step, jnp.zeros((B, di, ds)), seq)
+    y = (jnp.moveaxis(y, 0, 1) + xc * p["D"]) * jax.nn.silu(z)
+    return dot(y, p["out_proj"]["W"]), xpad[:, -(dc - 1):], \
+        jnp.swapaxes(h, 1, 2)
+
+
+@pytest.mark.parametrize("case", ["one-shot", "two-chunks", "padded-last"])
+def test_mamba_scan_matches_per_token_recurrence(case):
+    """``mamba_mix`` (its scan token by token on the pooled (B, d_state,
+    d_inner) state, in windows of 8) against the plain float32 per-token
+    recurrence: in one shot over 19 tokens (the last window padded); in
+    two calls of 8 carrying ``h`` and the conv window; and with a final
+    call right-padded to 8 of which 5 are real (``valid_len``).  Outputs,
+    conv window and ``h`` agree within 1e-5 of their largest magnitude:
+    the two sum the 16 d_state products in other orders."""
+    cfg = dataclasses.replace(get_smoke_config("jamba2-mini-ep2"),
+                              compute_dtype="float32", param_dtype="float32",
+                              mamba_d_state=16, mamba_chunk=8)
+    assert cfg.mamba_dtbc_norm and not cfg.epitome.enabled
+    ks = jax.random.split(KEY, 6)
+    p = ssm.init_mamba(ks[0], cfg, prefix="L0/mixer")
+    p["dt_proj"]["b"] = jax.random.normal(ks[1], p["dt_proj"]["b"].shape)
+    p["conv_b"] = 0.1 * jax.random.normal(ks[2], p["conv_b"].shape)
+    for i, n in enumerate(("dt_norm", "b_norm", "c_norm")):
+        p[n] = 0.2 * jax.random.normal(ks[3 + i], p[n].shape)
+    x = 2.0 * jax.random.normal(jax.random.PRNGKey(3), (2, 19, cfg.d_model))
+    mix = lambda t, state, vl=None: ssm.mamba_mix(
+        p, t, cfg, state, prefix="L0/mixer", valid_len=vl)
+    if case == "one-shot":
+        S = 19
+        out, (conv, h) = mix(x, None)
+    elif case == "two-chunks":
+        S = 16
+        a, st = mix(x[:, :8], None)
+        b, (conv, h) = mix(x[:, 8:16], st)
+        out = jnp.concatenate([a, b], axis=1)
+    else:
+        S = 13
+        a, st = mix(x[:, :8], None)
+        last = jnp.concatenate([x[:, 8:13], x[:, :3]], axis=1)   # 3 pads
+        b, (conv, h) = mix(last, st, jnp.int32(5))
+        out = jnp.concatenate([a, b[:, :5]], axis=1)
+    want = _mamba_plain(p, x, S)
+    assert h.shape == (2, 16, cfg.mamba_d_inner)
+    for got, ref_ in zip((out, conv, h), want):
+        got, ref_ = np.asarray(got), np.asarray(ref_)
+        scale = np.abs(ref_).max()
+        assert scale > 0.1
+        np.testing.assert_allclose(got, ref_, atol=1e-5 * scale, rtol=0)
