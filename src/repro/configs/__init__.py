@@ -2,10 +2,8 @@
 
 ``get_config(arch_id, **overrides)`` returns the full published config;
 ``get_smoke_config(arch_id)`` returns a reduced same-family config for CPU
-tests.  ``input_specs(cfg, shape)`` builds ShapeDtypeStruct stand-ins for
-every model input of a named input-shape cell (no allocation).
+tests.
 """
 from .registry import (
-    ARCHS, RESNET_ARCHS, SHAPES, get_config, get_resnet, get_smoke_config,
-    input_specs, shape_applicable,
+    ARCHS, RESNET_ARCHS, get_config, get_resnet, get_smoke_config,
 )

@@ -125,7 +125,7 @@ def deepseek_67b(ep: EpitomeSettings) -> ModelConfig:
 
 def musicgen_large(ep: EpitomeSettings) -> ModelConfig:
     # decoder-only over EnCodec tokens; the EnCodec frontend is a STUB:
-    # input_specs supplies precomputed frame embeddings
+    # the model takes precomputed frame embeddings
     return ModelConfig(
         name="musicgen-large", n_layers=48, d_model=2048, n_heads=32,
         n_kv_heads=32, d_ff=8192, vocab=2048,
@@ -159,7 +159,3 @@ BUILDERS = {
     "musicgen-large": musicgen_large,
     "internvl2-76b": internvl2_76b,
 }
-
-# archs able to run the 500k-token decode cell (sub-quadratic / O(1)-state
-# sequence mixing; see DESIGN.md §6 for the skip rationale)
-LONG_CONTEXT_OK = {"rwkv6-7b", "jamba-1.5-large-398b", "gemma2-2b"}

@@ -1,33 +1,13 @@
-"""Registry + input-shape cells (ShapeDtypeStruct stand-ins, no allocation)."""
+"""Registry: architecture ids and named epitome variants to configs."""
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional
-
-import jax
-import jax.numpy as jnp
 
 from ..models.config import EpitomeSettings, ModelConfig
-from .archs import BUILDERS, LONG_CONTEXT_OK
+from .archs import BUILDERS
 
 ARCHS = tuple(BUILDERS)
-
-
-@dataclasses.dataclass(frozen=True)
-class ShapeCell:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str            # train | prefill | decode
-
-
-SHAPES: Dict[str, ShapeCell] = {
-    "train_4k": ShapeCell("train_4k", 4096, 256, "train"),
-    "prefill_32k": ShapeCell("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k": ShapeCell("decode_32k", 32768, 128, "decode"),
-    "long_500k": ShapeCell("long_500k", 524288, 1, "decode"),
-}
 
 
 def epitome_settings(variant: str) -> EpitomeSettings:
@@ -186,40 +166,3 @@ def get_smoke_config(arch: str, epitome: str = "off",
         mamba_d_state=4, mamba_d_conv=4, mamba_expand=2,
         epitome=ep,
     )
-
-
-def shape_applicable(arch: str, shape: str) -> bool:
-    """long_500k only for sub-quadratic archs (skip noted in DESIGN.md §6)."""
-    if shape == "long_500k":
-        return arch in LONG_CONTEXT_OK
-    return True
-
-
-def input_specs(cfg: ModelConfig, shape: ShapeCell | str) -> Dict[str, Any]:
-    """ShapeDtypeStruct stand-ins for every input of a cell.
-
-    train:   {tokens|embeds, labels, mask}
-    prefill: {tokens|embeds}
-    decode:  {token, pos}        (the state is built by the launcher)
-    """
-    cell = SHAPES[shape] if isinstance(shape, str) else shape
-    B, S = cell.global_batch, cell.seq_len
-    f = jax.ShapeDtypeStruct
-    if cell.kind == "train":
-        batch: Dict[str, Any] = {
-            "labels": f((B, S), jnp.int32),
-            "mask": f((B, S), jnp.float32),
-        }
-        if cfg.embed_inputs:
-            batch["embeds"] = f((B, S, cfg.d_model), jnp.bfloat16)
-            batch["tokens"] = f((B, S), jnp.int32)
-        else:
-            batch["tokens"] = f((B, S), jnp.int32)
-        return batch
-    if cell.kind == "prefill":
-        if cfg.embed_inputs:
-            return {"inputs": f((B, S, cfg.d_model), jnp.bfloat16)}
-        return {"inputs": f((B, S), jnp.int32)}
-    # decode: one new token against a cache of length S
-    return {"token": f((B, 1), jnp.int32),
-            "pos": f((), jnp.int32)}

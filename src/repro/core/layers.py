@@ -402,10 +402,6 @@ def apply_linear(params: dict, x: Array, cfg: EpLayerConfig) -> Array:
     return y
 
 
-def param_count(params: dict) -> int:
-    return sum(int(np.prod(v.shape)) for v in jax.tree.leaves(params))
-
-
 # ---------------------------------------------------------------------------
 # Conv2D (NHWC) — for the paper's own ResNet-50/101 evaluation
 # ---------------------------------------------------------------------------

@@ -1,3 +1,3 @@
-"""Launchers: production mesh, multi-pod dry-run, roofline analysis,
-runnable train/serve drivers, and the EpitomePlan CLI
+"""Launchers: host meshes, the continuous-batching engine, runnable
+train/serve drivers, and the EpitomePlan CLI
 (`python -m repro.launch.plan` — search | legalize | show | run)."""

@@ -1,13 +1,13 @@
 """JAX's persistent compilation cache, placed from outside or in the
 checkout.
 
-Entry points (``chip_smoke.py``, ``repro.launch.serve``, the benchmarks)
-call ``enable_compile_cache()`` once, before they compile anything.  Where
-``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and nothing is
-set here.  Otherwise the cache lives at one fixed path inside the checkout
-(``<repo>/.jax_cache``, gitignored): the directory is part of what a later
-process must find again, so it is never derived from a temp name, a
-process id or the time.
+Entry points (``chip_smoke.py``, ``repro.launch.serve``, ``bench/run.py``,
+``benchmarks/run.py``) call ``enable_compile_cache()`` once, before they
+compile anything.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads
+it itself and nothing is set here.  Otherwise the cache lives at one fixed
+path inside the checkout (``<repo>/.jax_cache``, gitignored): the
+directory is part of what a later process must find again, so it is never
+derived from a temp name, a process id or the time.
 """
 from __future__ import annotations
 

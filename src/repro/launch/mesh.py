@@ -1,4 +1,4 @@
-"""Production mesh definitions.
+"""Host mesh definitions.
 
 Defined as FUNCTIONS so importing this module never touches jax device
 state (jax locks the device count on first backend init)."""
@@ -9,15 +9,6 @@ from typing import Tuple
 
 import jax
 from jax.sharding import AxisType
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
-    """Single pod: 16x16 = 256 chips (data, model).
-    Multi-pod: 2x16x16 = 512 chips (pod, data, model); the pod axis is pure
-    data parallelism whose gradient all-reduce crosses the DCN."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
@@ -63,9 +54,3 @@ def mesh_for_plan(plan, data: int = 1, model: int = 1) -> jax.sharding.Mesh:
                       + "; ".join(reasons), stacklevel=2)
     return mesh
 
-
-# TPU v5e-class hardware constants (per chip) used by the roofline
-PEAK_FLOPS_BF16 = 197e12          # FLOP/s
-HBM_BW = 819e9                    # B/s
-ICI_BW = 50e9                     # B/s per link
-HBM_BYTES = 16 * 2**30            # capacity

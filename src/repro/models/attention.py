@@ -25,10 +25,6 @@ Array = jax.Array
 
 NEG_INF = -2.0 ** 30   # large-but-finite: keeps fully-masked rows NaN-free
 
-# Dry-run knob: fully unroll the KV-chunk scan so XLA cost analysis counts
-# every chunk (while bodies are otherwise counted once).
-UNROLL_KV = False
-
 
 # ---------------------------------------------------------------------------
 # Parameters
@@ -115,8 +111,7 @@ def _chunk_attn(q, k, v, q_offset, kv_chunk, causal, window, cap):
     l0 = shard(jnp.zeros((B, Sq, H), jnp.float32), BATCH_AXES, None, TENSOR_AXIS)
     o0 = shard(jnp.zeros((B, Sq, H, hd), jnp.float32),
                BATCH_AXES, None, TENSOR_AXIS, None)
-    (m, l, o), _ = jax.lax.scan(body, (m0, l0, o0), jnp.arange(nchunks),
-                                unroll=nchunks if UNROLL_KV else 1)
+    (m, l, o), _ = jax.lax.scan(body, (m0, l0, o0), jnp.arange(nchunks))
     o = o / jnp.maximum(l[..., None], 1e-30)
     return o.astype(q.dtype)
 

@@ -58,11 +58,6 @@ def shard(x: Array, *spec) -> Array:
         x, constrained_sharding(mesh, P(*spec), x.shape))
 
 
-def batch_spec(extra: int = 0):
-    """P over batch then `extra` unsharded dims."""
-    return (BATCH_AXES,) + (None,) * extra
-
-
 # ---------------------------------------------------------------------------
 # Norms
 # ---------------------------------------------------------------------------

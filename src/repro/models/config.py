@@ -143,13 +143,12 @@ class ModelConfig:
     rwkv_lora_decay: int = 64
     rwkv_lora_mix: int = 32
 
-    # chunking (memory/perf trade-offs; the dry-run cost probes override
-    # these so inner scans can be fully unrolled for FLOP counting)
+    # chunking (memory/perf trade-offs)
     attn_kv_chunk: int = 512
     rwkv_chunk: int = 64
     mamba_chunk: int = 128
 
-    # distribution/perf knobs (§Perf hillclimb levers)
+    # distribution/perf knobs
     seq_shard_residual: bool = True    # Megatron-SP residual (False = pure TP)
     remat_policy: str = "nothing"      # nothing | dots (save matmul outputs)
     kv_cache_bits: int = 16            # 8 = int8 KV cache w/ per-tile scales
@@ -250,32 +249,3 @@ class ModelConfig:
                         f"({M}, {N})")
                 return lc
         return self.epitome.layer_config(M, N)
-
-    # -- parameter counting (MODEL_FLOPS uses 6*N*D / 6*N_active*D) ----------
-    def param_count(self, active_only: bool = False) -> int:
-        d, ff, hd = self.d_model, self.d_ff, self.hd
-        n = self.vocab * d  # embedding
-        if not self.tie_embeddings:
-            n += d * self.vocab
-        for kind, ffn in self.full_pattern:
-            reps = self.n_groups
-            if kind in (LayerKind.ATTN.value, LayerKind.ATTN_LOCAL.value):
-                n += reps * (d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd
-                             + self.n_heads * hd * d)
-            elif kind == LayerKind.MAMBA.value:
-                di, ds = self.mamba_d_inner, self.mamba_d_state
-                n += reps * (d * 2 * di + di * self.mamba_d_conv
-                             + di * (ds * 2 + 2 * self.dt_rank + ds) + di * d)
-            elif kind == LayerKind.RWKV.value:
-                n += reps * (4 * d * d + d * self.rwkv_lora_decay * 2
-                             + 5 * d * self.rwkv_lora_mix * 2)
-            if ffn == "moe":
-                lo, hi = self.held_experts
-                e = hi - lo if not active_only else self.top_k
-                n += reps * (e * 3 * d * ff + d * self.n_experts)
-            elif ffn == "dense":
-                mult = 3 if self.act in ("silu", "gelu") else 2
-                n += reps * (mult * d * ff)
-            if kind == LayerKind.RWKV.value and ffn == "rwkv_ffn":
-                pass
-        return n

@@ -24,12 +24,6 @@ from .config import LayerKind, ModelConfig
 
 Array = jax.Array
 
-# Dry-run knob: scan-over-groups unroll factor.  XLA's cost analysis counts
-# a while-loop body ONCE regardless of trip count, so the roofline lowering
-# sets this to n_groups (full unroll) to make FLOP/byte/collective counts
-# reflect the whole network.  Training memory analysis uses the default 1.
-SCAN_UNROLL = 1
-
 
 # ---------------------------------------------------------------------------
 # Init
@@ -251,8 +245,7 @@ def forward(params: Dict[str, Any], inputs: Array, cfg: ModelConfig,
     def scan_fn(x, group_params):
         return body(group_params, x), None
 
-    x, _ = jax.lax.scan(scan_fn, x, params["groups"],
-                        unroll=min(SCAN_UNROLL, cfg.n_groups))
+    x, _ = jax.lax.scan(scan_fn, x, params["groups"])
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params.get("head", None)
     if head is None:
@@ -286,33 +279,6 @@ def init_decode_state(cfg: ModelConfig, batch: int, max_len: int) -> Dict[str, A
     def stack_init(leaf):
         return jnp.zeros((cfg.n_groups,) + leaf.shape, leaf.dtype)
     return jax.tree.map(stack_init, one)
-
-
-def state_specs(cfg: ModelConfig, state_shape: Dict[str, Any],
-                batch: int) -> Dict[str, Any]:
-    """KV caches: batch over data when it divides, else sequence over
-    ('data','model') (long-context single-request decode)."""
-    def leaf(path, l):
-        shp = l.shape
-        if (path.endswith("/k") or path.endswith("/v")
-                or path.endswith("/k_s") or path.endswith("/v_s")):
-            # (G, B, Smax, Hkv, hd|1) — sequence sharded over 'model'
-            if batch > 1:
-                return P(None, BATCH_AXES, TENSOR_AXIS, None, None)
-            return P(None, None, ("pod", "data", "model"), None, None)
-        if path.endswith("/s"):            # rwkv state (G,B,H,K,V)
-            return P(None, BATCH_AXES if batch > 1 else None, TENSOR_AXIS, None, None)
-        if path.endswith("/h"):            # mamba state (G,B,ds,di)
-            return P(None, BATCH_AXES if batch > 1 else None, None, TENSOR_AXIS)
-        if path.endswith("/conv"):         # (G,B,dc-1,di)
-            return P(None, BATCH_AXES if batch > 1 else None, None, TENSOR_AXIS)
-        return P(*([None] * len(shp)))
-
-    def walk(tree, prefix):
-        if isinstance(tree, dict):
-            return {k: walk(v, f"{prefix}/{k}") for k, v in tree.items()}
-        return leaf(prefix, tree)
-    return walk(state_shape, "")
 
 
 # ---------------------------------------------------------------------------
@@ -365,8 +331,7 @@ def prefill(params: Dict[str, Any], inputs: Array, state: Dict[str, Any],
                                      chunk_start=chunk_start)
         return x, new_state
 
-    x, new_states = jax.lax.scan(scan_fn, x, (params["groups"], state),
-                                 unroll=min(SCAN_UNROLL, cfg.n_groups))
+    x, new_states = jax.lax.scan(scan_fn, x, (params["groups"], state))
     x_last = (x[:, -1:] if valid_len is None else
               jax.lax.dynamic_slice_in_dim(x, valid_len - 1, 1, 1))
     x = rms_norm(x_last, params["final_norm"], cfg.norm_eps)
@@ -411,8 +376,7 @@ def decode_step(params: Dict[str, Any], state: Dict[str, Any], token: Array,
 
     n0 = jnp.zeros((x.shape[0],), jnp.int32)
     (x, state, n), _ = jax.lax.scan(
-        scan_fn, (x, state, n0), (jnp.arange(cfg.n_groups), params["groups"]),
-        unroll=min(SCAN_UNROLL, cfg.n_groups))
+        scan_fn, (x, state, n0), (jnp.arange(cfg.n_groups), params["groups"]))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params.get("head", params["embed"].T if cfg.tie_embeddings else None)
     logits = unembed(x, head, cfg.logit_softcap)

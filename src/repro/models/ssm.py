@@ -31,10 +31,6 @@ from .config import ModelConfig, layer_name as _nm
 Array = jax.Array
 
 
-# Dry-run knob: fully unroll chunk scans for XLA cost analysis (while
-# bodies are otherwise counted once).
-UNROLL_CHUNKS = False
-
 # Tokens per iteration of Mamba's token-serial scan: 1, 4, 8 and 16 timed
 # within 4% of each other on a TPU v5e at Jamba2-Mini's widths, 16 the
 # fastest (PERF.md).
@@ -182,8 +178,7 @@ def rwkv_chunked(r, k, v, logw, u, state, chunk: int = 64):
     state = state.astype(jnp.float32)
     inputs = (rf.transpose(1, 0, 2, 3, 4), kf.transpose(1, 0, 2, 3, 4),
               vf.transpose(1, 0, 2, 3, 4), lw.transpose(1, 0, 2, 3, 4))
-    state, outs = jax.lax.scan(body, state, inputs,
-                               unroll=n if UNROLL_CHUNKS else 1)
+    state, outs = jax.lax.scan(body, state, inputs)
     out = outs.transpose(1, 0, 2, 3, 4).reshape(B, n * L, H, K)
     return out[:, :S], state
 
@@ -429,13 +424,11 @@ def _mamba_core(p, xi, z, conv_buf, h0, cfg: ModelConfig, chunk: int,
     def chunk_fn(h, inp):
         # nested remat: the backward of one window keeps its L states
         # (B, ds, di) and no more; recomputing one window's forward is cheap
-        return jax.lax.scan(step, h, inp,
-                            unroll=L if UNROLL_CHUNKS else TOKEN_UNROLL)
+        return jax.lax.scan(step, h, inp, unroll=TOKEN_UNROLL)
 
     seq = tuple(chunks(t.astype(jnp.float32)) for t in (dt, xc, Bp, Cp))
     with jax.named_scope("epim.mamba.scan"):
-        h_last, y = jax.lax.scan(chunk_fn, h0, seq,
-                                 unroll=n if UNROLL_CHUNKS else 1)
+        h_last, y = jax.lax.scan(chunk_fn, h0, seq)
     y = y.transpose(2, 0, 1, 3).reshape(B, n * L, di)[:, :S]
     y = y + xc.astype(jnp.float32) * p["D"][None, None]
     y = (y.astype(z.dtype)) * jax.nn.silu(z)

@@ -25,7 +25,9 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from ..core.layers import constrained_sharding
 from ..models import lm
 from ..models.config import ModelConfig
 from .optimizer import AdamWConfig, adamw_init, adamw_update, global_norm
@@ -76,6 +78,45 @@ def init_state(key: Array, cfg: ModelConfig, opt_cfg: AdamWConfig,
         state["ef_residual"] = jax.tree.map(
             lambda p: jnp.zeros(p.shape, jnp.float32), params)
     return state
+
+
+def state_sharding_tree(cfg: ModelConfig, opt_cfg: AdamWConfig,
+                        train_cfg: TrainConfig = TrainConfig()):
+    """(abstract state, matching PartitionSpecs) for ``init_state``.
+
+    Optimizer moments mirror the parameter specs; int8 moments are tuples
+    (q, scale) that both inherit the param's spec (q is shape-preserving,
+    the scale has the same rank with a smaller last dim)."""
+    state_shape = jax.eval_shape(
+        partial(init_state, cfg=cfg, opt_cfg=opt_cfg, train_cfg=train_cfg),
+        jax.random.PRNGKey(0))
+    p_specs = lm.param_specs(cfg, state_shape["params"])
+    if opt_cfg.moments_dtype == "int8":
+        m_specs = jax.tree.map(lambda sp: (sp, sp), p_specs,
+                               is_leaf=lambda x: isinstance(x, P))
+    else:
+        m_specs = p_specs
+    specs: Dict[str, Any] = {
+        "params": p_specs,
+        "step": P(),
+        "opt": {"step": P(), "m": m_specs, "v": m_specs},
+    }
+    if "master" in state_shape["opt"]:
+        specs["opt"]["master"] = p_specs
+    if "ef_residual" in state_shape:
+        specs["ef_residual"] = p_specs
+    return state_shape, specs
+
+
+def shaped(tree_shape: Any, tree_spec: Any, mesh) -> Any:
+    """ShapeDtypeStructs with shardings attached (no allocation); mesh axes
+    that are absent or do not divide a dim are dropped."""
+    def one(sds, spec):
+        return jax.ShapeDtypeStruct(
+            sds.shape, sds.dtype,
+            sharding=constrained_sharding(mesh, spec, sds.shape))
+    return jax.tree.map(one, tree_shape, tree_spec,
+                        is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
 
 
 def make_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig,

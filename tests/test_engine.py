@@ -373,16 +373,28 @@ def test_serve_deprecated_flags_warn(monkeypatch, capsys):
     assert "generated (1, 2)" in out
 
 
-def test_serving_bench_smoke(engine_factory):
-    """The open-loop Poisson driver completes every request and its
-    replayed request is bit-identical to the one-shot path."""
-    from benchmarks.serving_bench import run_serving
-    m = run_serving(n_requests=3, rate_hz=200.0, max_new=3, capacity=2,
-                    max_len=MAX_LEN)
-    assert m["completed"] == 3
-    assert m["bit_identical"] is True
-    assert m["tok_s"] > 0
-    assert 0 < m["p50_ttft_ms"] <= m["p99_ttft_ms"]
+def test_arrivals_between_steps_complete(engine_factory):
+    """Requests that arrive while the engine is running — each submitted
+    after one step(), three through two slots — all complete in full, with
+    consistent per-request timings."""
+    eng = engine_factory(capacity=2)
+    rng = np.random.default_rng(4)
+    reqs = [Request(prompt=_prompt(rng, p, eng.cfg.vocab), max_new_tokens=n,
+                    seed=i)
+            for i, (p, n) in enumerate([(5, 4), (11, 3), (7, 5)])]
+    handles = []
+    for r in reqs:
+        eng.step()
+        handles.append(eng.submit(r))
+    eng.drain()
+    for r, h in zip(reqs, handles):
+        c = h.result()
+        assert len(c.tokens) == r.max_new_tokens
+        assert c.prompt_len == len(r.prompt)
+        assert 0 < c.ttft_s <= c.latency_s
+        assert len(c.token_times) == r.max_new_tokens
+        assert all(a <= b for a, b in zip(c.token_times, c.token_times[1:]))
+    assert eng.stats["completed"] == 3
 
 
 # ---------------------------------------------------------------------------

@@ -137,8 +137,8 @@ def test_mini_multipod_dryrun():
         cfg = get_smoke_config("jamba-1.5-large-398b")
         opt = AdamWConfig(moments_dtype="int8")
         tc = TrainConfig(grad_accum=2)
-        from repro.launch.dryrun import shaped, state_sharding_tree
-        state_shape, specs = state_sharding_tree(cfg, mesh, tc, opt)
+        from repro.train.loop import shaped, state_sharding_tree
+        state_shape, specs = state_sharding_tree(cfg, opt, tc)
         state_in = shaped(state_shape, specs, mesh)
         B, S = 8, 32
         batch = {
