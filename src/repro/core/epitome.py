@@ -17,7 +17,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from functools import partial
 from typing import Optional, Tuple
 
 import jax
@@ -228,6 +227,29 @@ def wrapped_matmul(x: Array, E: Array, spec: EpitomeSpec) -> Array:
     return jnp.concatenate(pieces, axis=-1)
 
 
+def fold_rows(x: Array, spec: EpitomeSpec) -> Array:
+    """IFRT analogue: sum each virtual input row into its epitome row,
+    (..., M) -> (..., m).
+
+    Virtual row block g (rows g*bm up to M) lands on epitome rows
+    ``row_offsets()[g]`` on.  The offsets are static, so each block is a
+    static slice of x padded with zeros to the epitome's width at its
+    offset, and the blocks are added in ascending g: the order of
+    ``jax.ops.segment_sum`` and of the fused-fold kernel, with no scatter
+    (the zeros the pads add are exact).  On the CPU its bits equal
+    ``segment_sum``'s; on a TPU, XLA may keep a fused chain of bf16 adds
+    in f32 and round once."""
+    lead = [(0, 0)] * (x.ndim - 1)
+    out = None
+    for g, off in enumerate(spec.row_offsets()):
+        block = jax.lax.slice_in_dim(x, g * spec.bm,
+                                     min(spec.M, (g + 1) * spec.bm), axis=-1)
+        off = int(off)
+        block = jnp.pad(block, lead + [(off, spec.m - off - block.shape[-1])])
+        out = block if out is None else out + block
+    return out
+
+
 def folded_matmul(x: Array, E: Array, spec: EpitomeSpec) -> Array:
     """Epitome-space matmul (beyond-paper TPU optimization; DESIGN.md §2).
 
@@ -243,13 +265,8 @@ def folded_matmul(x: Array, E: Array, spec: EpitomeSpec) -> Array:
     (§5.3) is subsumed: duplicated column blocks become repeated gather
     indices.  Exact (no approximation); gradients flow by transposition.
     """
-    rmap = jnp.asarray(spec.row_index_map())
     cmap = jnp.asarray(spec.col_index_map())
-    # fold: scatter-add x columns into epitome-row space
-    xt = jnp.moveaxis(x, -1, 0)                       # (M, ...)
-    folded = jax.ops.segment_sum(xt, rmap, num_segments=spec.m)
-    folded = jnp.moveaxis(folded, 0, -1)              # (..., m)
-    y_ep = folded @ E.astype(x.dtype)                 # (..., n)
+    y_ep = fold_rows(x, spec) @ E.astype(x.dtype)     # (..., n)
     return jnp.take(y_ep, cmap, axis=-1)              # (..., N)
 
 

@@ -8,8 +8,8 @@ trace time instead of runtime).  Duplicated ``cb`` entries ARE the paper's
 output channel wrapping — the same E block is re-read from VMEM, which is
 free, instead of recomputed.
 
-The fold (IFRT analogue — virtual rows scatter-added into epitome rows) is
-a cheap bandwidth-bound segment-sum done by the ops.py wrapper; this kernel
+The fold (IFRT analogue — virtual rows summed into epitome rows) is a
+cheap bandwidth-bound slice-add done by the ops.py wrapper; this kernel
 is the MXU hot loop.
 
 Grid: (T/bt, gn, m/bk), k innermost for accumulation.  VMEM per step:

@@ -1,7 +1,7 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 `epitome_matmul` is what core/layers.py mode="kernel" calls: it folds the
-activations into epitome-row space (the IFRT analogue, a cheap segment-sum),
+activations into epitome-row space (the IFRT analogue, static slice-adds),
 runs the MXU kernel with the static OFAT offset table, and trims the result
 to the virtual width.  On the CPU backend (the unit tests) the kernels run
 in Pallas interpret mode; on the chip the same code compiles to Mosaic.
@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.epitome import EpitomeSpec
+from ..core.epitome import EpitomeSpec, fold_rows as _fold_rows
 from ..core.quant import QuantConfig, quantize_epitome_packed
 from .epitome_matmul import epitome_matmul_blocks
 from .quant_epitome_matmul import (quant_epitome_matmul_blocks,
@@ -67,13 +67,10 @@ def col_blocks_splittable(spec: EpitomeSpec, bn: int) -> bool:
 
 @jax.named_scope("epim.fold")
 def fold_rows(x: jax.Array, spec: EpitomeSpec) -> jax.Array:
-    """IFRT analogue: scatter-add virtual fan-in into epitome rows.  Its
-    device ops carry ``epim.fold`` in their op_name (profile reductions
-    group the fold's time by it)."""
-    rmap = jnp.asarray(spec.row_index_map())
-    xt = jnp.moveaxis(x, -1, 0)
-    folded = jax.ops.segment_sum(xt, rmap, num_segments=spec.m)
-    return jnp.moveaxis(folded, 0, -1)
+    """IFRT analogue: ``core.epitome.fold_rows``, the static slice-add of
+    virtual fan-in into epitome rows.  Its device ops carry ``epim.fold``
+    in their op_name (profile reductions group the fold's time by it)."""
+    return _fold_rows(x, spec)
 
 
 def epitome_matmul(x: jax.Array, E: jax.Array, spec: EpitomeSpec,

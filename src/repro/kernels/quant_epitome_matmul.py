@@ -35,11 +35,12 @@ Two pipelining levers live here (the kernel half of kernels/autotune.py):
     k loop: the next block's HBM->VMEM copy overlaps the current dot.
   * ``quant_epitome_matmul_fused_fold`` takes the *unfolded* activation
     plus the scalar-prefetched row-offset table and performs the fold_rows
-    segment-sum into a VMEM scratch inside the kernel, so on the decode
+    slice-add into a VMEM scratch inside the kernel, so on the decode
     path the folded activation never round-trips HBM between the fold and
     the matmul.  Contributions accumulate in ascending virtual-block order
-    — the same order as jax.ops.segment_sum — so the fold is bit-identical
-    to the ops.fold_rows + quant_epitome_matmul_blocks path.
+    — the same order as ops.fold_rows' slice-adds — so on the CPU the
+    fold is bit-identical to the ops.fold_rows + quant_epitome_matmul_blocks
+    path (on a TPU, XLA may round that path's fused bf16 adds once).
 """
 from __future__ import annotations
 
@@ -129,7 +130,7 @@ def _fused_fold_kernel(cb_ref, ro_ref, xt_ref, q_ref, s_ref, z_ref, o_ref,
     @pl.when((pl.program_id(1) == 0) & (pl.program_id(2) == 0))
     def _fold():
         fold_ref[...] = jnp.zeros_like(fold_ref)
-        for i in range(gm):   # ascending block order == segment_sum order
+        for i in range(gm):   # ascending block order, as ops.fold_rows
             off = ro_ref[i]
             fold_ref[pl.dslice(off, bm), :] = (
                 fold_ref[pl.dslice(off, bm), :]

@@ -6,10 +6,11 @@ import pytest
 from hypothesis_compat import given, settings, st
 
 from repro.core.epitome import (
-    EpitomeSpec, epitome_matmul_ref, epitomize_dense, folded_matmul,
-    init_epitome, overlap_counts, overlap_mask, plan_epitome, reconstruct,
-    wrapped_matmul,
+    EpitomeSpec, epitome_matmul_ref, epitomize_dense, fold_rows,
+    folded_matmul, init_epitome, overlap_counts, overlap_mask, plan_epitome,
+    reconstruct, wrapped_matmul,
 )
+from repro.kernels import ops
 
 KEY = jax.random.PRNGKey(0)
 
@@ -110,6 +111,59 @@ class TestReconstruction:
         err_fit = float(jnp.mean((reconstruct(E_fit, s) - W) ** 2))
         err_rand = float(jnp.mean((reconstruct(E_rand, s) - W) ** 2))
         assert err_fit < err_rand
+
+
+def _segment_sum_fold(x, spec):
+    """The fold as a scatter-add: jax.ops.segment_sum over the static row
+    map, with the virtual rows moved to the front and back."""
+    xt = jnp.moveaxis(x, -1, 0)
+    folded = jax.ops.segment_sum(xt, jnp.asarray(spec.row_index_map()),
+                                 num_segments=spec.m)
+    return jnp.moveaxis(folded, 0, -1)
+
+
+# Every fold the rwkv6-7b and jamba2-mini-ep2 kernel-q3 configs run (the
+# row map depends on M, m and bm alone), a ragged last block, and equal
+# offsets (m == bm: every block lands on the same rows).
+FOLD_SPECS = {
+    "4096-1024-n4096": EpitomeSpec(4096, 4096, 1024, 4096),
+    "4096-1024-n14336": EpitomeSpec(4096, 14336, 1024, 14336),
+    "4096-1024-n16384": EpitomeSpec(4096, 16384, 1024, 16384),
+    "14336-3584": EpitomeSpec(14336, 4096, 3584, 4096),
+    "8192-2048": EpitomeSpec(8192, 4096, 2048, 4096),
+    "4096-4096-n256": EpitomeSpec(4096, 1024, 4096, 256),
+    "ragged": EpitomeSpec(1000, 256, 384, 256, bm=128, bn=128),
+    "equal-offsets": EpitomeSpec(700, 256, 256, 256, bm=256, bn=256),
+}
+
+
+class TestFoldRows:
+    @pytest.mark.parametrize("lead", [(32,), (2, 64)], ids=["T", "BS"])
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                             ids=["bf16", "f32"])
+    @pytest.mark.parametrize("name", list(FOLD_SPECS))
+    def test_equals_segment_sum_bit_for_bit(self, name, dtype, lead):
+        spec = FOLD_SPECS[name]
+        x = jax.random.normal(jax.random.PRNGKey(spec.M), lead + (spec.M,),
+                              jnp.float32).astype(dtype)
+        got = jax.jit(fold_rows, static_argnums=1)(x, spec)
+        want = jax.jit(_segment_sum_fold, static_argnums=1)(x, spec)
+        assert got.shape == lead + (spec.m,) and got.dtype == dtype
+        bits = np.uint16 if dtype == jnp.bfloat16 else np.uint32
+        np.testing.assert_array_equal(np.asarray(got).view(bits),
+                                      np.asarray(want).view(bits))
+
+    def test_expert_down_fold_lowers_without_scatter(self):
+        """The 14336 -> 3584 fold at a 64-row decode step: static slices
+        and adds, where segment_sum lowers to a scatter."""
+        spec = FOLD_SPECS["14336-3584"]
+        x = jnp.zeros((64, spec.M), jnp.bfloat16)
+        text = jax.jit(ops.fold_rows, static_argnums=1).lower(
+            x, spec).as_text()
+        assert "scatter" not in text
+        assert "slice" in text and "add" in text
+        assert "scatter" in jax.jit(_segment_sum_fold, static_argnums=1).lower(
+            x, spec).as_text()
 
 
 class TestOverlap:

@@ -309,8 +309,8 @@ class TestPickBkAndOverrides:
     def test_fused_fold_bit_identical(self, spec_kw):
         """The fused-fold variant (fold inside the kernel, VMEM-resident)
         accumulates row blocks in ascending order exactly like
-        fold_rows' segment_sum, so its output is bit-identical to the
-        standard kernel's."""
+        fold_rows' slice-adds, so on the CPU its output is bit-identical
+        to the standard kernel's."""
         from repro.core.quant import QuantConfig
         spec = EpitomeSpec(**spec_kw)
         E = jax.random.normal(KEY, (spec.m, spec.n))

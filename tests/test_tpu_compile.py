@@ -228,6 +228,9 @@ def test_jamba2_share_decode_step(one_chip, monkeypatch):
     assert not pool_shapes & set(allocs), allocs
     copies = re.findall(r"= (\w+\[[\d,]*\])\{[^}]*\} copy\(", text)
     assert not pool_shapes & set(copies), copies
+    # the fold is static slices and adds: no scatter under it
+    fold = [l for l in text.splitlines() if "/epim.fold/" in l]
+    assert fold and not any(" scatter(" in l for l in fold)
 
 
 @pytest.mark.parametrize("B,S", [(64, 1), (1, 128)], ids=["decode", "chunk"])

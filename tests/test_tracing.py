@@ -175,11 +175,14 @@ def test_layer_ops_carry_the_dispatch_and_fold_scopes():
     params = prepack_linear(
         init_linear(jax.random.PRNGKey(0), SPEC.M, SPEC.N, cfg), cfg)
     x = jnp.ones((8, SPEC.M), jnp.bfloat16)
-    text = jax.jit(lambda p, x: apply_linear(p, x, cfg)).lower(
-        params, x).as_text(debug_info=True)
-    locs = [l for l in text.splitlines() if l.startswith("#loc")]
+    lowered = jax.jit(lambda p, x: apply_linear(p, x, cfg)).lower(params, x)
+    locs = [l for l in lowered.as_text(debug_info=True).splitlines()
+            if l.startswith("#loc")]
     assert any("/epim.epitome_matmul/" in l for l in locs)
-    assert any("epim.fold/scatter-add" in l for l in locs)
+    fold = [l for l in lowered.compile().as_text().splitlines()
+            if "/epim.epitome_matmul/" in l and "/epim.fold/" in l]
+    assert any(" add(" in l for l in fold)
+    assert not any(" scatter(" in l for l in fold)
 
 
 def test_wkv6_kernel_ops_carry_its_name():
